@@ -12,6 +12,8 @@ import numpy as np
 
 
 def main():
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=40)
     ap.add_argument("--arch", default="h2o-danube-1.8b")

@@ -73,6 +73,8 @@ def collect_pretrain(t_end: float = 1800.0) -> dict[str, np.ndarray]:
 
 
 def main():
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--minutes", type=int, default=30)
     ap.add_argument("--shards", type=int, default=0,

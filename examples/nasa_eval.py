@@ -10,6 +10,8 @@ import json
 
 
 def main():
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--days", type=int, default=2)
     args = ap.parse_args()

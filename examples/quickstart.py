@@ -185,6 +185,8 @@ if __name__ == "__main__":
                          "(make_forecaster kind; 'attn' = the fused "
                          "Attention-Double-LSTM)")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     guardrail_demo(quick=args.quick, forecaster=args.forecaster)
     if not args.quick:
         ppa_demo()

@@ -11,6 +11,8 @@ import argparse
 
 
 def main():
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--steps", type=int, default=None)

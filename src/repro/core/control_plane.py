@@ -64,7 +64,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.evaluator import EvalResult
-from repro.core.forecaster import (LSTMForecaster, _lstm_forward_stacked,
+from repro.core.faults import FaultLog
+from repro.core.forecaster import (LSTMForecaster, forward_stacked_staged,
                                    lstm_stack_signature, stack_params,
                                    stack_scaler_stats, transform_stacked)
 from repro.core.metrics import N_METRICS, MetricsHistory, Snapshot
@@ -366,7 +367,7 @@ def predict_from_stack(cache, idx, wins, m0, n_total: int,
     z = transform_stacked(wins, mean_s, std_s)
     stacked = (cache["stacked"] if len(idx) == n_total
                else jax.tree.map(lambda leaf: leaf[idx], cache["stacked"]))
-    preds = np.asarray(_lstm_forward_stacked(
+    preds = np.asarray(forward_stacked_staged(
         stacked, jnp.asarray(z),
         use_pallas=m0.use_pallas if use_pallas is None else use_pallas,
         arch=m0.arch))
@@ -397,9 +398,13 @@ class _VecShard:
 
     vectorized = True
 
-    def __init__(self, cfg, specs, model, use_pallas: bool | None = None):
+    def __init__(self, cfg, specs, model, use_pallas: bool | None = None,
+                 faults: FaultLog | None = None):
         self.cfg = cfg
         self.use_pallas = use_pallas     # None = inherit from the models
+        # forecast failures served reactively are counted here (the
+        # plane's log; a standalone shard keeps its own)
+        self.faults = faults if faults is not None else FaultLog()
         self.specs = list(specs)
         self.names = [s.name for s in specs]
         self.index = {n: i for i, n in enumerate(self.names)}
@@ -555,8 +560,10 @@ class _VecShard:
                     if ss is not None:
                         stds = np.full((Zs, N_METRICS), np.nan)
                         stds[cand] = ss
-                except Exception:
-                    # robust: batched model failure -> every target reactive
+                except Exception as e:
+                    # robust: batched model failure -> every target
+                    # reactive, counted (a build fault propagates)
+                    self.faults.forecast_failed(e)
                     means[:] = np.nan
                     stds = None
                     cand = np.zeros(Zs, bool)
@@ -578,7 +585,8 @@ class _VecShard:
             if cand.any():
                 try:
                     means[cand] = self._predict_stacked(ring, cand)
-                except Exception:
+                except Exception as e:
+                    self.faults.forecast_failed(e)
                     means[:] = np.nan
                     cand = np.zeros(Zs, bool)
         return means, stds, bayes, cand
@@ -960,6 +968,27 @@ class TickResult(cabc.Mapping):
                 out[idx] = [rec[n].replicas for n in shard.names]
         return out
 
+    def forecasts_array(self) -> tuple[np.ndarray, np.ndarray]:
+        """The tick's forecasts in plane target order: ``(means (Z, M)``
+        with NaN rows where no forecast ran, ``cand (Z,)`` bool``)`` — the
+        columnar twin of each ``EvalResult.raw_prediction``."""
+        Z = len(self._plane._names)
+        means = np.full((Z, N_METRICS), np.nan)
+        cand = np.zeros(Z, bool)
+        for shard, idx in self._plane._shard_rows:
+            rec = self._by_shard[id(shard)]
+            if shard.vectorized:
+                if rec[6] is not None:
+                    means[idx] = rec[6]
+                cand[idx] = rec[7]
+                continue
+            for gi, n in zip(idx, shard.names):
+                raw = rec[n].raw_prediction
+                if raw is not None:
+                    means[gi] = raw
+                    cand[gi] = True
+        return means, cand
+
 
 class ShardedControlPlane:
     """S-shard staged control plane with double-buffered async ticks and
@@ -1012,9 +1041,12 @@ class ShardedControlPlane:
         self._shard_rows: list[tuple[object, np.ndarray]] = []
         self._shard_of: dict[str, object] = {}
         pos = self._pos = {n: i for i, n in enumerate(self._names)}
+        # forecast errors and dropped refits, behind degraded_stats()
+        self.faults = FaultLog()
         for s in sorted(by_shard):
             specs = by_shard[s]
-            shard = (_VecShard(cfg, specs, model, use_pallas=use_pallas)
+            shard = (_VecShard(cfg, specs, model, use_pallas=use_pallas,
+                               faults=self.faults)
                      if _vectorizable(specs, model)
                      else _CtrlShard(cfg, specs, model))
             self.shards.append(shard)
@@ -1083,7 +1115,7 @@ class ShardedControlPlane:
         if device_mesh is not None:
             from repro.core.device_plane import engine_for_plane
             self._engine, self._dev_models = engine_for_plane(
-                self, device_mesh, coalesce_dispatch)
+                self, device_mesh, coalesce_dispatch, self.faults)
             self._fused = False          # the engine owns dispatch
             Z = len(self._names)
             self._dev_counts = np.zeros(Z, np.int64)
@@ -1482,8 +1514,11 @@ class ShardedControlPlane:
     def degraded_stats(self) -> dict:
         """Cumulative degraded-mode counters: targets held on stale
         metrics, ticks served reactively (stale + crash + deadline), the
-        failover and snapshot machinery — ``FleetController`` exposes the
-        same keys, so A/B harnesses read one dict shape."""
+        failover and snapshot machinery, forecast dispatches that failed
+        (``forecast_errors``, each served reactively) and dropped refits
+        (``refit_failures``); ``self.faults.last_error`` keeps the latest
+        failure's text.  ``FleetController`` exposes the same keys, so A/B
+        harnesses read one dict shape."""
         stale = sum(s.degraded_counts() for s in self.shards)
         d = self._deg
         return {"stale_targets": stale,
@@ -1492,7 +1527,9 @@ class ShardedControlPlane:
                 "deadline_skips": d["deadline_skips"],
                 "failovers": d["failovers"],
                 "recovery_ticks": d["recovery_ticks"],
-                "snapshots": d["snapshots"]}
+                "snapshots": d["snapshots"],
+                "forecast_errors": self.faults.forecast_errors,
+                "refit_failures": self.faults.refit_failures}
 
     # ------------------------------------------------------ fused dispatch -
     def _refresh_fused_cache(self) -> dict:
@@ -1566,8 +1603,10 @@ class ShardedControlPlane:
                 else:
                     means_g, stds_g = self.model.predict_batch(wins)
                     bayes = self.model.is_bayesian
-            except Exception:
-                # robust: a failed gang dispatch -> every target reactive
+            except Exception as e:
+                # robust: a failed gang dispatch -> every target reactive,
+                # counted (a build fault propagates)
+                self.faults.forecast_failed(e)
                 means_g = stds_g = None
                 bayes = False
         out, off = [], 0
@@ -1676,10 +1715,11 @@ class ShardedControlPlane:
         self._refit = None               # cleared first: a failed compute
         try:                             # must not wedge every later tick
             fut.result()
-        except Exception:
-            # robustness guarantee: a failed refit is dropped and the plane
-            # keeps serving with the previous params (the snapshot history
-            # is lost, like a crashed out-of-band trainer)
+        except Exception as e:
+            # robustness guarantee: a failed refit is dropped (and counted)
+            # and the plane keeps serving with the previous params (the
+            # snapshot history is lost, like a crashed out-of-band trainer)
+            self.faults.refit_failed(e)
             self.refit_log.append(
                 {"t": pending.t, "submitted": wall,
                  "applied": time.monotonic(), "failed": True,

@@ -35,6 +35,7 @@ from repro.core.control_plane import (Guardrail, Tick, as_replica_map,
                                       stage_forecast, stage_formulate,
                                       stage_guard, validate_targets)
 from repro.core.evaluator import Evaluator, EvalResult
+from repro.core.faults import FaultLog
 from repro.core.forecaster import (Forecaster, LSTMForecaster,
                                    lstm_predict_batch_stacked,
                                    lstm_stack_signature)
@@ -92,6 +93,7 @@ class FleetController:
         self._last_update_t = 0.0
         self._stack_cache: dict = {}   # stacked-params reuse across ticks
         self._deg_stale = 0            # target-ticks held on stale metrics
+        self.faults = FaultLog()       # forecasts served reactively
         # last fresh-tick decision per target: the degraded hold's anchor
         # (stage_degrade) — k8s keeps desiredReplicas on missing metrics
         self._deg_last: dict[str, int] = {}
@@ -130,7 +132,9 @@ class FleetController:
         return {"stale_targets": self._deg_stale,
                 "reactive_fallbacks": self._deg_stale,
                 "deadline_skips": 0, "failovers": 0,
-                "recovery_ticks": 0, "snapshots": 0}
+                "recovery_ticks": 0, "snapshots": 0,
+                "forecast_errors": self.faults.forecast_errors,
+                "refit_failures": self.faults.refit_failures}
 
     # -------------------------------------------------------- formulator --
     def observe(self, name: str, snap: Snapshot, fresh: bool = True):
@@ -206,12 +210,14 @@ class FleetController:
                         try:
                             mean, std = m.predict(r)
                             out[n] = (mean, std, m.is_bayesian)
-                        except Exception:
-                            pass
+                        except Exception as e:
+                            self.faults.forecast_failed(e)
                     return out
-        except Exception:
+        except Exception as e:
             # Robust: batched model failure -> every target falls back to
-            # its current metric (same guarantee as Evaluator.evaluate)
+            # its current metric (same guarantee as Evaluator.evaluate),
+            # counted; a program that fails to build propagates
+            self.faults.forecast_failed(e)
             return {}
         if stds is None:
             stds = [None] * len(cand)

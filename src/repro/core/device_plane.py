@@ -48,9 +48,9 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core.faults import FaultLog, Staged
 from repro.core.forecaster import (ARCH_PARAM_LEAVES, Z_CLIP,
                                    lstm_stack_signature, stack_scaler_stats,
                                    stacked_forward)
@@ -95,7 +95,7 @@ class DevicePlaneEngine:
     def __init__(self, Z: int, window: int, residual: bool,
                  use_pallas: bool, *, device_mesh=None,
                  coalesce_dispatch: bool = True, ring_rows: int | None = None,
-                 arch: str = "lstm"):
+                 arch: str = "lstm", faults: FaultLog | None = None):
         self.mesh = (device_mesh if device_mesh is not None
                      and not isinstance(device_mesh, int)
                      else control_mesh(device_mesh))
@@ -113,6 +113,7 @@ class DevicePlaneEngine:
         self.R = int(ring_rows if ring_rows is not None
                      else max(self.window + 1, 8))
         self.coalesce = bool(coalesce_dispatch)
+        self.faults = faults if faults is not None else FaultLog()
         self._s_rows = NamedSharding(self.mesh, P(CONTROL_AXIS, None))
         self._s_ring = NamedSharding(self.mesh, P(CONTROL_AXIS, None, None))
         self.ring = jax.device_put(
@@ -126,7 +127,9 @@ class DevicePlaneEngine:
         self._valid = np.zeros(self.Z, bool)
         self._push = jax.jit(self._push_fn)
         self._push_row = jax.jit(self._push_row_fn)
-        self._fwd = self._build_forward()
+        self._fwd = Staged(forecast_program(
+            self.mesh, self.window, self.residual, self.use_pallas,
+            self.arch, self.coalesce))
 
     # ----------------------------------------------------- ring updates --
     @staticmethod
@@ -205,34 +208,6 @@ class DevicePlaneEngine:
             return False
 
     # --------------------------------------------------------- dispatch --
-    def _build_forward(self):
-        W, residual, use_pallas = self.window, self.residual, self.use_pallas
-        arch = self.arch
-
-        def body(stacked, mean, std, ring):
-            win = ring[:, -W:, :]
-            z = jnp.clip((win - mean[:, None, :]) / std[:, None, :],
-                         -Z_CLIP, Z_CLIP)
-            net = stacked_forward(stacked, z, use_pallas=use_pallas,
-                                  arch=arch)
-            if residual:
-                net = z[:, -1, :] + net
-            return net * std + mean
-
-        if self.coalesce:
-            # gang dispatch: ONE program, GSPMD partitions the Z axis over
-            # the mesh following the argument shardings
-            return jax.jit(body)
-        # per-shard dispatch: shard_map runs the block program per device
-        # (PartitionSpecs shorter than an array's rank replicate the
-        # trailing dims; the stacked-params dict takes P('shards') as a
-        # pytree prefix)
-        return jax.jit(shard_map(
-            body, mesh=self.mesh,
-            in_specs=(P(CONTROL_AXIS), P(CONTROL_AXIS), P(CONTROL_AXIS),
-                      P(CONTROL_AXIS)),
-            out_specs=P(CONTROL_AXIS)))
-
     def forecast(self, ring_ref, counts: np.ndarray, stale=None):
         """Forecast every target from a ring snapshot: returns
         ``(means (Z, M) f32 with NaN rows for non-candidates, cand (Z,))``.
@@ -255,14 +230,58 @@ class DevicePlaneEngine:
             else:
                 means = np.full((self.Z, N_METRICS), np.nan, np.float32)
                 means[cand] = np.asarray(out)[:self.Z][cand]
-        except Exception:
-            # robust: a failed gang dispatch -> every target reactive
+        except Exception as e:
+            # robust: a failed gang dispatch -> every target reactive,
+            # counted; a program that fails to build raises ProgramFault
+            self.faults.forecast_failed(e)
             return np.full((self.Z, N_METRICS), np.nan, np.float32), \
                 np.zeros(self.Z, bool)
         return means, cand
 
 
-def engine_for_plane(plane, device_mesh, coalesce_dispatch: bool
+def forecast_program(mesh, window: int, residual: bool, use_pallas: bool,
+                     arch: str, coalesce: bool):
+    """The engine's jitted forecast program: ``(stacked, mean, std, ring)``
+    with a leading padded target axis on each -> ``(Zp, M)`` forecasts in
+    metric units (standardise, stacked forward, residual, inverse)."""
+    W = window
+    rows = (P(CONTROL_AXIS), P(CONTROL_AXIS))
+
+    def net_fn(stacked, z):
+        return stacked_forward(stacked, z, use_pallas=use_pallas, arch=arch)
+
+    if coalesce and use_pallas:
+        # GSPMD cannot partition a Mosaic kernel: the gang program runs the
+        # kernel per device under shard_map and partitions the rest itself
+        net_fn = jax.shard_map(net_fn, mesh=mesh, in_specs=rows,
+                               out_specs=P(CONTROL_AXIS), check_vma=False)
+
+    def body(stacked, mean, std, ring):
+        win = ring[:, -W:, :]
+        z = jnp.clip((win - mean[:, None, :]) / std[:, None, :],
+                     -Z_CLIP, Z_CLIP)
+        net = net_fn(stacked, z)
+        if residual:
+            net = z[:, -1, :] + net
+        return net * std + mean
+
+    if coalesce:
+        # gang dispatch: ONE program, GSPMD partitions the Z axis over the
+        # mesh following the argument shardings
+        return jax.jit(body)
+    # per-shard dispatch: shard_map runs the block program per device
+    # (PartitionSpecs shorter than an array's rank replicate the trailing
+    # dims; the stacked-params dict takes P('shards') as a pytree prefix).
+    # Every row is computed on its own device and nothing is replicated,
+    # so there is no varying-axes typing to check — and the Pallas
+    # kernels' out_shapes carry none, which check_vma would refuse.
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=rows + rows, out_specs=P(CONTROL_AXIS),
+        check_vma=False))
+
+
+def engine_for_plane(plane, device_mesh, coalesce_dispatch: bool,
+                     faults: FaultLog | None = None
                      ) -> tuple[DevicePlaneEngine, list]:
     """Validate a ``ShardedControlPlane``'s target set for the device path
     and build its engine + plane-order model list.  The device plane only
@@ -293,5 +312,5 @@ def engine_for_plane(plane, device_mesh, coalesce_dispatch: bool
     engine = DevicePlaneEngine(
         len(models), m0.window, m0.residual, use_pallas,
         device_mesh=device_mesh, coalesce_dispatch=coalesce_dispatch,
-        ring_rows=m0.window, arch=m0.arch)
+        ring_rows=m0.window, arch=m0.arch, faults=faults)
     return engine, models

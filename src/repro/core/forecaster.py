@@ -19,8 +19,12 @@ block-batched sequence kernel (``kernels/lstm_seq.py``, DESIGN.md §7):
 the whole W-step window runs inside ONE kernel with (h, c) resident in
 VMEM scratch, for both the shared-weights layout (``lstm_forward``) and
 the stacked per-target layout (``_lstm_forward_stacked`` — Z independently
-trained LSTMs, batched-GEMV gate matmuls).  The kernel carries a
+trained LSTMs, per-row GEMV gate matmuls).  The kernel carries a
 checkpoint-style custom VJP, so the fit paths differentiate through it.
+
+The batched forecasts run their programs through ``faults.Staged``: a
+forward that cannot be built raises ``ProgramFault``, which the control
+plane never serves as a reactive tick.
 """
 from __future__ import annotations
 
@@ -33,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.faults import Staged
 from repro.core.metrics import N_METRICS
 from repro.training.optimizer import AdamWConfig, adamw_init, adamw_update
 
@@ -105,6 +110,9 @@ class Scaler:
 
 
 # ------------------------------------------------------------------ LSTM ---
+# inits are jitted: one dispatch per model instead of one per random draw,
+# which dominates building Z >= 10^4 models on a TPU host
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
 def _lstm_init(key, n_in: int, hidden: int, n_out: int):
     k1, k2, k3 = jax.random.split(key, 3)
     s = 1.0 / np.sqrt(hidden)
@@ -117,6 +125,7 @@ def _lstm_init(key, n_in: int, hidden: int, n_out: int):
     }
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
 def _attn_init(key, n_in: int, hidden: int, n_out: int):
     """Attention-Double-LSTM parameters: two LSTM layers bridged by a
     window-length temporal-attention block (query projection ``Wa``)."""
@@ -245,6 +254,9 @@ def lstm_forward(params, xs, *, use_pallas: bool = False,
     return jax.nn.relu(h) @ params["Wo"] + params["bo"]
 
 
+lstm_forward_staged = Staged(lstm_forward)
+
+
 @functools.partial(jax.jit, static_argnames=("opt_cfg", "epochs",
                                              "use_pallas", "arch"))
 def _lstm_fit(params, opt_state, X, Y, opt_cfg, epochs, use_pallas=False,
@@ -354,9 +366,9 @@ class LSTMForecaster(Forecaster):
             wins = np.stack([np.asarray(r, np.float64)[-self.window:]
                              for r in recents])
         z = self.scaler.transform(wins)
-        pred = np.asarray(lstm_forward(self.params, jnp.asarray(z),
-                                       use_pallas=self.use_pallas,
-                                       arch=self.arch))
+        pred = np.asarray(lstm_forward_staged(self.params, jnp.asarray(z),
+                                              use_pallas=self.use_pallas,
+                                              arch=self.arch))
         if self.residual:
             pred = z[:, -1] + pred
         return self.scaler.inverse(pred), None
@@ -490,11 +502,14 @@ def _lstm_forward_stacked(stacked_params, xs, *, use_pallas: bool = False,
                           arch: str = "lstm"):
     """stacked_params: pytree with leading target axis Z; xs (Z, W, M) ->
     (Z, M).  One device dispatch for all Z targets: the Pallas path is the
-    fused block-batched sequence kernel (per-row weights, batched-GEMV
+    fused block-batched sequence kernel (per-row weights, per-row GEMV
     gate matmuls, W-step fori_loop in VMEM scratch); the XLA path vmaps
     the scan forward."""
     return stacked_forward(stacked_params, xs, use_pallas=use_pallas,
                            arch=arch)
+
+
+forward_stacked_staged = Staged(_lstm_forward_stacked)
 
 
 def lstm_predict_batch_stacked(models: list["LSTMForecaster"], recents,
@@ -526,9 +541,9 @@ def lstm_predict_batch_stacked(models: list["LSTMForecaster"], recents,
             # they were taken from stay alive (address reuse after gc would
             # otherwise let a fresh model hit a stale cache entry)
             cache["models"] = list(models)
-    preds = np.asarray(_lstm_forward_stacked(stacked, jnp.asarray(z),
-                                             use_pallas=m0.use_pallas,
-                                             arch=m0.arch))
+    preds = np.asarray(forward_stacked_staged(stacked, jnp.asarray(z),
+                                              use_pallas=m0.use_pallas,
+                                              arch=m0.arch))
     if m0.residual:
         preds = z[:, -1] + preds
     means = np.stack([m.scaler.inverse(p)
@@ -602,6 +617,9 @@ class BatchFitResult:
     def apply(self):
         for models, scalers, stacked, losses in self._groups:
             losses = np.asarray(losses)
+            # one device read per leaf; each model keeps host views into
+            # it (slicing on the device would be Z x leaves dispatches)
+            stacked = jax.tree.map(np.asarray, stacked)
             for i, m in enumerate(models):
                 m.scaler = scalers[i]
                 m.params = jax.tree.map(lambda leaf, i=i: leaf[i], stacked)
@@ -666,30 +684,35 @@ def lstm_fit_batch_stacked(models: list["LSTMForecaster"], serieses,
         groups[(m.epochs if scratch else m.finetune_epochs,
                 scratch)].append((m, s))
     for (epochs, scratch), pairs in groups.items():
-        ms, Xs, Ys, ps, scalers = [], [], [], [], []
+        ms, Xs, Ys, scalers = [], [], [], []
         for m, s in pairs:
             if scratch:
                 sc = Scaler()
                 sc.fit(s)
-                p = m._init_params(jax.random.PRNGKey(
-                    getattr(m, "_seed", 0)))
             else:
-                sc, p = m.scaler, m.params
+                sc = m.scaler
             z = sc.transform(s)
             Xs.append(np.stack([z[i:i + W] for i in range(len(z) - W)]))
             Ys.append(z[W:] - z[W - 1:-1] if m.residual else z[W:])
             ms.append(m)
-            ps.append(p)
             scalers.append(sc)
-        stacked_p = jax.tree.map(lambda *ls: jnp.stack(ls), *ps)
-        stacked_o = jax.tree.map(lambda *ls: jnp.stack(ls),
-                                 *[adamw_init(p, m0.opt_cfg) for p in ps])
+        # params and optimiser state are built stacked, a few dispatches
+        # for the whole group rather than a few per model
+        if scratch:
+            # each model's own seed, as fit() would use it
+            seeds = jnp.asarray([getattr(m, "_seed", 0) for m in ms])
+            stacked_p = jax.vmap(
+                lambda seed: m0._init_params(jax.random.PRNGKey(seed)))(seeds)
+        else:
+            stacked_p = stack_params(ms)
+        stacked_o = jax.vmap(lambda p: adamw_init(p, m0.opt_cfg))(stacked_p)
         lens = {len(x) for x in Xs}
+        static = (m0.opt_cfg, epochs, m0.use_pallas, m0.arch)
         if len(lens) == 1:
-            new_p, _, losses = _lstm_fit_stacked(
-                stacked_p, stacked_o, jnp.asarray(np.stack(Xs)),
-                jnp.asarray(np.stack(Ys)), m0.opt_cfg, epochs,
-                m0.use_pallas, m0.arch)
+            new_p, losses = _fit_in_chunks(
+                _lstm_fit_stacked,
+                (stacked_p, stacked_o, jnp.asarray(np.stack(Xs)),
+                 jnp.asarray(np.stack(Ys))), static)
         else:
             # ragged: pad to the longest window batch, mask the padding
             n_max = max(lens)
@@ -700,12 +723,48 @@ def lstm_fit_batch_stacked(models: list["LSTMForecaster"], serieses,
                 Xp[i, :len(x)] = x
                 Yp[i, :len(y)] = y
                 Wt[i, :len(x)] = 1.0
-            new_p, _, losses = _lstm_fit_stacked_masked(
-                stacked_p, stacked_o, jnp.asarray(Xp), jnp.asarray(Yp),
-                jnp.asarray(Wt), m0.opt_cfg, epochs, m0.use_pallas,
-                m0.arch)
+            new_p, losses = _fit_in_chunks(
+                _lstm_fit_stacked_masked,
+                (stacked_p, stacked_o, jnp.asarray(Xp), jnp.asarray(Yp),
+                 jnp.asarray(Wt)), static)
         result.add(ms, scalers, new_p, losses)
     return result.apply() if apply else result
+
+
+def _device_bytes_free() -> int | None:
+    """Bytes the default device can still allocate, where its backend
+    reports memory (a TPU does; the CPU does not)."""
+    stats = jax.devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+def _fit_in_chunks(fit, arrays, static, probe: int = 256):
+    """Run a stacked fit program over its leading target axis in as few
+    equal chunks as the device's free memory holds; returns ``(params,
+    losses)``.  The footprint per target is read from the program
+    compiled for a probe of ``probe`` targets; a backend that reports no
+    memory runs one dispatch.  Targets are independent rows, so the split
+    changes no target's result."""
+    n = len(arrays[2])
+    k = n
+    free = _device_bytes_free()
+    if free is not None and n > probe:
+        mem = fit.lower(*jax.tree.map(lambda a: a[:probe], arrays),
+                        *static).compile().memory_analysis()
+        if mem is not None:
+            per_target = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                          + mem.output_size_in_bytes) / probe
+            k = max(1, min(n, int(0.8 * free / per_target)))
+    k = -(-n // -(-n // k))              # equal chunks of at most k
+    outs = [fit(*jax.tree.map(lambda a: a[i:i + k], arrays), *static)
+            for i in range(0, n, k)]
+    if len(outs) == 1:
+        return outs[0][0], outs[0][2]
+    params = jax.tree.map(lambda *ls: jnp.concatenate(ls),
+                          *[o[0] for o in outs])
+    return params, jnp.concatenate([o[2] for o in outs])
 
 
 # ------------------------------------------------------------------ ARMA ---
@@ -846,6 +905,9 @@ def _lstm_forward_members(stacked_params, xs, *, use_pallas: bool = False,
     return jax.vmap(fwd)(stacked_params, xs)
 
 
+_members_staged = Staged(_lstm_forward_members)
+
+
 class EnsembleForecaster(Forecaster):
     """Deep ensemble of LSTMs — the Bayesian path of Algorithm 1: predictive
     std across members is the (un)certainty compared against the PPA's
@@ -901,7 +963,7 @@ class EnsembleForecaster(Forecaster):
         if cache.get("gens") != gens:
             cache["gens"] = gens
             cache["stacked"] = stack_params(ms)
-        preds = np.asarray(_lstm_forward_members(
+        preds = np.asarray(_members_staged(
             cache["stacked"], jnp.asarray(z), use_pallas=m0.use_pallas,
             arch=m0.arch))
         if m0.residual:

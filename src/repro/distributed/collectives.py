@@ -13,7 +13,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def quantize_int8(x: jax.Array):
@@ -52,8 +51,8 @@ def make_compressed_grad_allreduce(mesh, data_axis: str = "data"):
             return mean.astype(g_shard.dtype), new_err.astype(err_shard.dtype)
 
         spec = P()  # replicated-per-shard view; grads already sharded by pjit
-        return shard_map(inner, mesh=mesh, in_specs=(spec, spec),
-                         out_specs=(spec, spec), check_rep=False)(g, err)
+        return jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec),
+                             out_specs=(spec, spec), check_vma=False)(g, err)
 
     def allreduce(grads, err):
         flat_g, tdef = jax.tree.flatten(grads)

@@ -9,7 +9,7 @@
 # submodules of the same name, so those names are the jitted callables —
 # internal code therefore imports implementations by full module path
 # (see ops.py), never through package attributes.
-from repro.kernels import compat, ref
+from repro.kernels import ref
 from repro.kernels import ops as _ops
 
 flash_attention = _ops.flash_attention
@@ -23,7 +23,7 @@ attn_lstm_seq_stacked = _ops.attn_lstm_seq_stacked
 rmsnorm = _ops.rmsnorm
 
 __all__ = [
-    "compat", "ref",
+    "ref",
     "flash_attention", "decode_attention", "ssd_scan", "lstm_cell",
     "lstm_seq", "lstm_seq_stacked",
     "attn_lstm_seq", "attn_lstm_seq_stacked",

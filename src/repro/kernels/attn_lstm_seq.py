@@ -22,8 +22,8 @@ Two layouts, mirroring ``lstm_seq``:
   gate/attention matmuls are plain GEMMs on the MXU;
 * ``attn_lstm_seq_stacked``  — per-row weights with a leading target axis:
   xs (Z, W, M), every param leaf (Z, ...) -> (Z, n_out); matmuls are
-  batched GEMVs via ``dot_general`` (Z independently trained per-target
-  forecasters in ONE dispatch).
+  per-row GEMVs (``lstm_seq.row_matvec``) — Z independently trained
+  per-target forecasters in ONE dispatch.
 
 Both carry the checkpoint-style ``jax.custom_vjp``: the forward saves only
 its inputs and the backward replays the pure-jnp reference
@@ -42,21 +42,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import compat, ref
-
-# dot_general dims for per-row weights: (bb, K) x (bb, K, N) -> (bb, N)
-_BATCHED_GEMV = (((1,), (1,)), ((0,), (0,)))
-
-
-def _gates(c, gx, gh, b, *, hidden):
-    """Shared gate math: pre-activations -> (h', c') in f32."""
-    gates = gx + gh + b
-    i = jax.nn.sigmoid(gates[:, 0 * hidden:1 * hidden])
-    f = jax.nn.sigmoid(gates[:, 1 * hidden:2 * hidden])
-    g = jnp.tanh(gates[:, 2 * hidden:3 * hidden])
-    o = jax.nn.sigmoid(gates[:, 3 * hidden:4 * hidden])
-    c2 = f * c + i * g
-    return o * jnp.tanh(c2), c2
+from repro.kernels import ref
+from repro.kernels.lstm_seq import _F32, _gates_step as _gates, _pad_rows, \
+    row_matvec
 
 
 def _attn_seq_kernel(xs_ref, wx1_ref, wh1_ref, b1_ref, wa_ref, wx2_ref,
@@ -67,7 +55,6 @@ def _attn_seq_kernel(xs_ref, wx1_ref, wh1_ref, b1_ref, wa_ref, wx2_ref,
     never leave VMEM."""
     h_ref[...] = jnp.zeros_like(h_ref)
     c_ref[...] = jnp.zeros_like(c_ref)
-    xs = xs_ref[...].astype(jnp.float32)
     wx1 = wx1_ref[...]
     wh1 = wh1_ref[...]
     b1 = b1_ref[...].astype(jnp.float32)
@@ -76,9 +63,12 @@ def _attn_seq_kernel(xs_ref, wx1_ref, wh1_ref, b1_ref, wa_ref, wx2_ref,
     b2 = b2_ref[...].astype(jnp.float32)
 
     def step1(t, carry):
-        x = jax.lax.dynamic_index_in_dim(xs, t, axis=1, keepdims=False)
-        gx = jax.lax.dot(x, wx1, preferred_element_type=jnp.float32)
-        gh = jax.lax.dot(h_ref[...], wh1,
+        # timesteps are read from refs: Mosaic lowers no dynamic_slice of
+        # a loaded value
+        x = xs_ref[:, t, :].astype(jnp.float32)
+        gx = jax.lax.dot(x, wx1, precision=_F32,
+                         preferred_element_type=jnp.float32)
+        gh = jax.lax.dot(h_ref[...], wh1, precision=_F32,
                          preferred_element_type=jnp.float32)
         h2, c2 = _gates(c_ref[...], gx, gh, b1, hidden=hidden)
         h_ref[...] = h2
@@ -90,20 +80,22 @@ def _attn_seq_kernel(xs_ref, wx1_ref, wh1_ref, b1_ref, wa_ref, wx2_ref,
 
     # temporal attention over the in-VMEM hidden history
     hs = hs_ref[...]                                     # (bb, W, H)
-    q = jax.lax.dot(h_ref[...], wa_ref[...],
+    q = jax.lax.dot(h_ref[...], wa_ref[...], precision=_F32,
                     preferred_element_type=jnp.float32)  # (bb, H)
     scores = jnp.sum(hs * q[:, None, :], axis=-1) * (hidden ** -0.5)
     alpha = jax.nn.softmax(scores, axis=-1)              # (bb, W)
-    ctx = alpha[:, :, None] * hs                         # (bb, W, H)
+    # the reweighted context sequence replaces the history in its scratch
+    hs_ref[...] = alpha[:, :, None] * hs                 # (bb, W, H)
 
     # second LSTM pass over the reweighted sequence (reuse (h, c) scratch)
     h_ref[...] = jnp.zeros_like(h_ref)
     c_ref[...] = jnp.zeros_like(c_ref)
 
     def step2(t, carry):
-        a = jax.lax.dynamic_index_in_dim(ctx, t, axis=1, keepdims=False)
-        gx = jax.lax.dot(a, wx2, preferred_element_type=jnp.float32)
-        gh = jax.lax.dot(h_ref[...], wh2,
+        a = hs_ref[:, t, :]
+        gx = jax.lax.dot(a, wx2, precision=_F32,
+                         preferred_element_type=jnp.float32)
+        gh = jax.lax.dot(h_ref[...], wh2, precision=_F32,
                          preferred_element_type=jnp.float32)
         h2, c2 = _gates(c_ref[...], gx, gh, b2, hidden=hidden)
         h_ref[...] = h2
@@ -111,7 +103,7 @@ def _attn_seq_kernel(xs_ref, wx1_ref, wh1_ref, b1_ref, wa_ref, wx2_ref,
         return carry
 
     jax.lax.fori_loop(0, window, step2, 0)
-    head = jax.lax.dot(jax.nn.relu(h_ref[...]), wo_ref[...],
+    head = jax.lax.dot(jax.nn.relu(h_ref[...]), wo_ref[...], precision=_F32,
                        preferred_element_type=jnp.float32)
     out_ref[...] = (head + bo_ref[...].astype(jnp.float32)
                     ).astype(out_ref.dtype)
@@ -122,24 +114,18 @@ def _attn_seq_stacked_kernel(xs_ref, wx1_ref, wh1_ref, b1_ref, wa_ref,
                              out_ref, h_ref, c_ref, hs_ref,
                              *, window, hidden):
     """Per-row-weights block: xs (bb, W, M), weight leaves (bb, ...); gate,
-    query and head matmuls are batched GEMVs (one MXU dispatch per block,
-    not one per target)."""
+    query and head matmuls are per-row GEMVs over the whole block."""
     h_ref[...] = jnp.zeros_like(h_ref)
     c_ref[...] = jnp.zeros_like(c_ref)
-    xs = xs_ref[...].astype(jnp.float32)
-    wx1 = wx1_ref[...]
-    wh1 = wh1_ref[...]
     b1 = b1_ref[...].astype(jnp.float32)
-    wx2 = wx2_ref[...]
-    wh2 = wh2_ref[...]
     b2 = b2_ref[...].astype(jnp.float32)
 
     def step1(t, carry):
-        x = jax.lax.dynamic_index_in_dim(xs, t, axis=1, keepdims=False)
-        gx = jax.lax.dot_general(x, wx1, _BATCHED_GEMV,
-                                 preferred_element_type=jnp.float32)
-        gh = jax.lax.dot_general(h_ref[...], wh1, _BATCHED_GEMV,
-                                 preferred_element_type=jnp.float32)
+        # weights are read from their refs at each use: a loaded copy held
+        # live across the loop would be a second VMEM buffer per weight
+        x = xs_ref[:, t, :].astype(jnp.float32)
+        gx = row_matvec(x, wx1_ref[...])
+        gh = row_matvec(h_ref[...], wh1_ref[...])
         h2, c2 = _gates(c_ref[...], gx, gh, b1, hidden=hidden)
         h_ref[...] = h2
         c_ref[...] = c2
@@ -149,39 +135,27 @@ def _attn_seq_stacked_kernel(xs_ref, wx1_ref, wh1_ref, b1_ref, wa_ref,
     jax.lax.fori_loop(0, window, step1, 0)
 
     hs = hs_ref[...]                                     # (bb, W, H)
-    q = jax.lax.dot_general(h_ref[...], wa_ref[...], _BATCHED_GEMV,
-                            preferred_element_type=jnp.float32)
+    q = row_matvec(h_ref[...], wa_ref[...])
     scores = jnp.sum(hs * q[:, None, :], axis=-1) * (hidden ** -0.5)
     alpha = jax.nn.softmax(scores, axis=-1)
-    ctx = alpha[:, :, None] * hs
+    hs_ref[...] = alpha[:, :, None] * hs
 
     h_ref[...] = jnp.zeros_like(h_ref)
     c_ref[...] = jnp.zeros_like(c_ref)
 
     def step2(t, carry):
-        a = jax.lax.dynamic_index_in_dim(ctx, t, axis=1, keepdims=False)
-        gx = jax.lax.dot_general(a, wx2, _BATCHED_GEMV,
-                                 preferred_element_type=jnp.float32)
-        gh = jax.lax.dot_general(h_ref[...], wh2, _BATCHED_GEMV,
-                                 preferred_element_type=jnp.float32)
+        a = hs_ref[:, t, :]
+        gx = row_matvec(a, wx2_ref[...])
+        gh = row_matvec(h_ref[...], wh2_ref[...])
         h2, c2 = _gates(c_ref[...], gx, gh, b2, hidden=hidden)
         h_ref[...] = h2
         c_ref[...] = c2
         return carry
 
     jax.lax.fori_loop(0, window, step2, 0)
-    head = jax.lax.dot_general(jax.nn.relu(h_ref[...]), wo_ref[...],
-                               _BATCHED_GEMV,
-                               preferred_element_type=jnp.float32)
+    head = row_matvec(jax.nn.relu(h_ref[...]), wo_ref[...])
     out_ref[...] = (head + bo_ref[...].astype(jnp.float32)
                     ).astype(out_ref.dtype)
-
-
-def _pad_rows(arrs, pad: int):
-    if not pad:
-        return arrs
-    return [jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-            for a in arrs]
 
 
 def _attn_seq_pallas(Wx1, Wh1, b1, Wa, Wx2, Wh2, b2, Wo, bo, xs,
@@ -195,6 +169,7 @@ def _attn_seq_pallas(Wx1, Wh1, b1, Wa, Wx2, Wh2, b2, Wo, bo, xs,
     pad = (-B) % block_b
     xs, = _pad_rows([xs], pad)
     nb = xs.shape[0] // block_b
+    # (1, N) bias rows, as in lstm_seq: the fit path vmaps this kernel
     kernel = functools.partial(_attn_seq_kernel, window=W, hidden=H)
     out = pl.pallas_call(
         kernel,
@@ -203,23 +178,24 @@ def _attn_seq_pallas(Wx1, Wh1, b1, Wa, Wx2, Wh2, b2, Wo, bo, xs,
             pl.BlockSpec((block_b, W, M), lambda i: (i, 0, 0)),
             pl.BlockSpec((M, 4 * H), lambda i: (0, 0)),
             pl.BlockSpec((H, 4 * H), lambda i: (0, 0)),
-            pl.BlockSpec((4 * H,), lambda i: (0,)),
+            pl.BlockSpec((1, 4 * H), lambda i: (0, 0)),
             pl.BlockSpec((H, H), lambda i: (0, 0)),
             pl.BlockSpec((H, 4 * H), lambda i: (0, 0)),
             pl.BlockSpec((H, 4 * H), lambda i: (0, 0)),
-            pl.BlockSpec((4 * H,), lambda i: (0,)),
+            pl.BlockSpec((1, 4 * H), lambda i: (0, 0)),
             pl.BlockSpec((H, n_out), lambda i: (0, 0)),
-            pl.BlockSpec((n_out,), lambda i: (0,)),
+            pl.BlockSpec((1, n_out), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_b, n_out), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((xs.shape[0], n_out), xs.dtype),
         scratch_shapes=[pltpu.VMEM((block_b, H), jnp.float32),
                         pltpu.VMEM((block_b, H), jnp.float32),
                         pltpu.VMEM((block_b, W, H), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(xs, Wx1, Wh1, b1, Wa, Wx2, Wh2, b2, Wo, bo)
+    )(xs, Wx1, Wh1, b1.reshape(1, -1), Wa, Wx2, Wh2, b2.reshape(1, -1), Wo,
+      bo.reshape(1, -1))
     return out[:B]
 
 
@@ -256,7 +232,7 @@ def _attn_seq_stacked_pallas(Wx1, Wh1, b1, Wa, Wx2, Wh2, b2, Wo, bo, xs,
         scratch_shapes=[pltpu.VMEM((block_b, H), jnp.float32),
                         pltpu.VMEM((block_b, H), jnp.float32),
                         pltpu.VMEM((block_b, W, H), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xs, Wx1, Wh1, b1, Wa, Wx2, Wh2, b2, Wo, bo)
@@ -328,6 +304,6 @@ def attn_lstm_seq_stacked(Wx1, Wh1, b1, Wa, Wx2, Wh2, b2, Wo, bo, xs,
                           *, block_b: int = 32, interpret: bool = False):
     """Per-target layout: xs (Z, W, M) and a leading Z axis on every weight
     leaf -> (Z, n_out).  Z independently parameterised Attention-Double-
-    LSTMs answered by ONE fused kernel (batched-GEMV matmuls per block)."""
+    LSTMs answered by ONE fused kernel (per-row GEMV matmuls per block)."""
     return _attn_seq_stacked_vjp(Wx1, Wh1, b1, Wa, Wx2, Wh2, b2, Wo, bo,
                                  xs, block_b, interpret)

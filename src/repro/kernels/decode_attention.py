@@ -12,7 +12,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import compat
 
 NEG_INF = -1e30
 
@@ -89,7 +88,7 @@ def decode_attention(q, k, v, *, kv_valid, cap=None, window=None, scale=None,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, D), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(valid, q.reshape(B, Hq, D), k.reshape(B * Hkv, S, D),

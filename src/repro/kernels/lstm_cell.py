@@ -12,7 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels import compat
+import jax.experimental.pallas.tpu as pltpu
 
 
 def _kernel(x_ref, h_ref, c_ref, wx_ref, wh_ref, b_ref, h2_ref, c2_ref, *,
@@ -65,7 +65,7 @@ def lstm_cell(Wx, Wh, b, h, c, x, *, block_b=128, interpret=False):
             jax.ShapeDtypeStruct((x.shape[0], H), h.dtype),
             jax.ShapeDtypeStruct((x.shape[0], H), c.dtype),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, h, c, Wx, Wh, b)

@@ -19,8 +19,8 @@ Two layouts:
   ``predict_batch`` and every fit-path forward);
 * ``lstm_seq_stacked``  — per-row weights with a leading target axis:
   xs (Z, W, M), every param leaf (Z, ...) -> (Z, n_out); the gate matmuls
-  are batched GEMVs expressed as ``dot_general`` with a batch dimension
-  (Z independently trained per-target LSTMs in ONE dispatch).
+  are per-row GEMVs (``row_matvec``: a VPU multiply and a reduce over K)
+  — Z independently trained per-target LSTMs in ONE dispatch.
 
 Both are differentiable via ``jax.custom_vjp`` with a checkpoint-style
 backward: the forward saves only its inputs and the backward replays the
@@ -39,10 +39,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import compat, ref
+from repro.kernels import ref
 
-# dot_general dims for per-row weights: (bb, K) x (bb, K, N) -> (bb, N)
-_BATCHED_GEMV = (((1,), (1,)), ((0,), (0,)))
+# f32 in, f32 out: the kernels keep the forecaster's f32 contract on the MXU
+_F32 = jax.lax.Precision.HIGHEST
+
+
+def row_matvec(x, w):
+    """Per-row weights: x (bb, K) and w (bb, K, N) -> (bb, N), row r being
+    ``x[r] @ w[r]``.  Written as a multiply and a reduce over K in f32:
+    Mosaic cannot encode the batched ``dot_general`` form, whose (bb, K)
+    lhs has no non-contracting dimension."""
+    return jnp.sum(x[:, :, None] * w, axis=1)
 
 
 def _gates_step(c, gx, gh, b, *, hidden):
@@ -61,15 +69,17 @@ def _seq_kernel(xs_ref, wx_ref, wh_ref, b_ref, wo_ref, bo_ref, out_ref,
     """Shared-weights block: xs (bb, W, M); weights whole in VMEM."""
     h_ref[...] = jnp.zeros_like(h_ref)
     c_ref[...] = jnp.zeros_like(c_ref)
-    xs = xs_ref[...].astype(jnp.float32)
     wx = wx_ref[...]
     wh = wh_ref[...]
     b = b_ref[...].astype(jnp.float32)
 
     def step(t, carry):
-        x = jax.lax.dynamic_index_in_dim(xs, t, axis=1, keepdims=False)
-        gx = jax.lax.dot(x, wx, preferred_element_type=jnp.float32)
-        gh = jax.lax.dot(h_ref[...], wh,
+        # timestep read from the ref: Mosaic lowers no dynamic_slice of a
+        # loaded value
+        x = xs_ref[:, t, :].astype(jnp.float32)
+        gx = jax.lax.dot(x, wx, precision=_F32,
+                         preferred_element_type=jnp.float32)
+        gh = jax.lax.dot(h_ref[...], wh, precision=_F32,
                          preferred_element_type=jnp.float32)
         h2, c2 = _gates_step(c_ref[...], gx, gh, b, hidden=hidden)
         h_ref[...] = h2
@@ -77,7 +87,7 @@ def _seq_kernel(xs_ref, wx_ref, wh_ref, b_ref, wo_ref, bo_ref, out_ref,
         return carry
 
     jax.lax.fori_loop(0, window, step, 0)
-    head = jax.lax.dot(jax.nn.relu(h_ref[...]), wo_ref[...],
+    head = jax.lax.dot(jax.nn.relu(h_ref[...]), wo_ref[...], precision=_F32,
                        preferred_element_type=jnp.float32)
     out_ref[...] = (head + bo_ref[...].astype(jnp.float32)
                     ).astype(out_ref.dtype)
@@ -86,30 +96,24 @@ def _seq_kernel(xs_ref, wx_ref, wh_ref, b_ref, wo_ref, bo_ref, out_ref,
 def _seq_stacked_kernel(xs_ref, wx_ref, wh_ref, b_ref, wo_ref, bo_ref,
                         out_ref, h_ref, c_ref, *, window, hidden):
     """Per-row-weights block: xs (bb, W, M), weight leaves (bb, ...); the
-    gate matmuls are batched GEMVs (one MXU dispatch per block, not one
-    per target)."""
+    gate matmuls are per-row GEMVs over the whole block."""
     h_ref[...] = jnp.zeros_like(h_ref)
     c_ref[...] = jnp.zeros_like(c_ref)
-    xs = xs_ref[...].astype(jnp.float32)
-    wx = wx_ref[...]
-    wh = wh_ref[...]
     b = b_ref[...].astype(jnp.float32)
 
     def step(t, carry):
-        x = jax.lax.dynamic_index_in_dim(xs, t, axis=1, keepdims=False)
-        gx = jax.lax.dot_general(x, wx, _BATCHED_GEMV,
-                                 preferred_element_type=jnp.float32)
-        gh = jax.lax.dot_general(h_ref[...], wh, _BATCHED_GEMV,
-                                 preferred_element_type=jnp.float32)
+        # weights are read from their refs at each use: a loaded copy held
+        # live across the loop would be a second VMEM buffer per weight
+        x = xs_ref[:, t, :].astype(jnp.float32)
+        gx = row_matvec(x, wx_ref[...])
+        gh = row_matvec(h_ref[...], wh_ref[...])
         h2, c2 = _gates_step(c_ref[...], gx, gh, b, hidden=hidden)
         h_ref[...] = h2
         c_ref[...] = c2
         return carry
 
     jax.lax.fori_loop(0, window, step, 0)
-    head = jax.lax.dot_general(jax.nn.relu(h_ref[...]), wo_ref[...],
-                               _BATCHED_GEMV,
-                               preferred_element_type=jnp.float32)
+    head = row_matvec(jax.nn.relu(h_ref[...]), wo_ref[...])
     out_ref[...] = (head + bo_ref[...].astype(jnp.float32)
                     ).astype(out_ref.dtype)
 
@@ -131,6 +135,9 @@ def _seq_pallas(Wx, Wh, b, Wo, bo, xs, *, block_b, interpret):
     pad = (-B) % block_b
     xs, = _pad_rows([xs], pad)
     nb = xs.shape[0] // block_b
+    # biases go in as (1, N) rows: the fit path vmaps this kernel over
+    # targets, and Mosaic refuses a block whose second-minor dimension is
+    # a squeezed batch axis
     kernel = functools.partial(_seq_kernel, window=W, hidden=H)
     out = pl.pallas_call(
         kernel,
@@ -139,18 +146,18 @@ def _seq_pallas(Wx, Wh, b, Wo, bo, xs, *, block_b, interpret):
             pl.BlockSpec((block_b, W, M), lambda i: (i, 0, 0)),
             pl.BlockSpec((M, 4 * H), lambda i: (0, 0)),
             pl.BlockSpec((H, 4 * H), lambda i: (0, 0)),
-            pl.BlockSpec((4 * H,), lambda i: (0,)),
+            pl.BlockSpec((1, 4 * H), lambda i: (0, 0)),
             pl.BlockSpec((H, n_out), lambda i: (0, 0)),
-            pl.BlockSpec((n_out,), lambda i: (0,)),
+            pl.BlockSpec((1, n_out), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_b, n_out), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((xs.shape[0], n_out), xs.dtype),
         scratch_shapes=[pltpu.VMEM((block_b, H), jnp.float32),
                         pltpu.VMEM((block_b, H), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(xs, Wx, Wh, b, Wo, bo)
+    )(xs, Wx, Wh, b.reshape(1, -1), Wo, bo.reshape(1, -1))
     return out[:B]
 
 
@@ -180,7 +187,7 @@ def _seq_stacked_pallas(Wx, Wh, b, Wo, bo, xs, *, block_b, interpret):
         out_shape=jax.ShapeDtypeStruct((xs.shape[0], n_out), xs.dtype),
         scratch_shapes=[pltpu.VMEM((block_b, H), jnp.float32),
                         pltpu.VMEM((block_b, H), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xs, Wx, Wh, b, Wo, bo)
@@ -247,5 +254,5 @@ def lstm_seq_stacked(Wx, Wh, b, Wo, bo, xs, *, block_b: int = 32,
                      interpret: bool = False):
     """Per-target layout: xs (Z, W, M) and a leading Z axis on every weight
     leaf -> (Z, n_out).  Z independently parameterised LSTMs answered by
-    ONE fused kernel (batched-GEMV gate matmuls per block)."""
+    ONE fused kernel (per-row GEMV gate matmuls per block)."""
     return _lstm_seq_stacked_vjp(Wx, Wh, b, Wo, bo, xs, block_b, interpret)

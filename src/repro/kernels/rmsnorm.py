@@ -8,7 +8,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels import compat
+import jax.experimental.pallas.tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, o_ref, *, eps):
@@ -35,7 +35,7 @@ def rmsnorm(x, w, *, eps=1e-6, block_rows=256, interpret=False):
         ],
         out_specs=pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], D), x.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w)

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hf_ref,
             state_ref, *, chunk, nc):
@@ -104,7 +102,7 @@ def ssd_scan(x, dt, A, Bm, Cm, D, *, chunk=128, interpret=False):
             jax.ShapeDtypeStruct((Bb, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xc, dtc, A, Bc, Cc, D)

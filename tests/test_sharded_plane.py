@@ -23,6 +23,7 @@ from repro.core import (FleetController, LSTMForecaster, MetricsHistory,
                         ThresholdPolicy, TargetUtilizationPolicy, Updater,
                         UpdatePolicy)
 from repro.core.control_plane import shard_assignment, stage_collect
+from repro.core.faults import ProgramFault, Staged
 from repro.core.forecaster import EnsembleForecaster, lstm_fit_batch_stacked
 
 from benchmarks.bench_control_plane import _traces
@@ -192,6 +193,34 @@ def test_custom_policy_falls_back_and_matches(base):
     _drive(traces, ref, plane)
 
 
+@pytest.mark.parametrize("opaque", [False, True])
+def test_forecasts_array_matches_per_target_results(base, opaque):
+    """``TickResult.forecasts_array`` is the columnar twin of every
+    target's ``raw_prediction``, on columnar and fallback shards."""
+    traces, models = base
+    specs = [TargetSpec(z, (_OpaquePolicy(100.0) if opaque and i == 0
+                            else ThresholdPolicy(100.0, 1)),
+                        model=copy.deepcopy(models[z]))
+             for i, z in enumerate(models)]
+    plane = ShardedControlPlane(CFG, specs, n_shards=2)
+    assert opaque != all(s.vectorized for s in plane.shards)
+    for k in range(120, 128):
+        t = 15.0 * (k - 119)
+        for z in traces:
+            plane.observe(z, Snapshot(t, traces[z][k]))
+        res = plane.control_step(t, 16, 2)
+        means, cand = res.forecasts_array()
+        for i, n in enumerate(plane.target_names):
+            raw = res[n].raw_prediction
+            assert cand[i] == (raw is not None)
+            if raw is None:
+                assert np.isnan(means[i]).all()
+            else:
+                np.testing.assert_array_equal(means[i], raw)
+    assert cand.all()
+    plane.shutdown()
+
+
 def test_async_tick_double_buffer_semantics(base):
     """Observations landing between begin_tick and finish_tick are next
     window's data: the in-flight tick decides on the snapshot."""
@@ -279,6 +308,48 @@ def test_batch_refit_ragged_pad_and_mask(base):
     assert all(len(h) == 0 for h in hists)
 
 
+@pytest.mark.parametrize("ragged", [False, True])
+def test_batch_fit_splits_to_fit_device_memory(monkeypatch, ragged):
+    """Where the device reports less free memory than one dispatch of the
+    stacked fit needs, the targets are fitted in equal chunks — with the
+    same result as one dispatch (targets are independent rows)."""
+    import repro.core.forecaster as fc
+
+    n = 300                                   # above the 256-target probe
+    rng = np.random.default_rng(5)
+    serieses = [np.abs(rng.normal(200, 40, (20 + (i % 3 if ragged else 0),
+                                            5))) for i in range(n)]
+
+    def fit(free):
+        monkeypatch.setattr(fc, "_device_bytes_free", lambda: free)
+        models = [LSTMForecaster(window=2, hidden=4, epochs=3, seed=i)
+                  for i in range(n)]
+        assert lstm_fit_batch_stacked(models, serieses, from_scratch=True)
+        return models
+
+    calls = []
+    real = fc._lstm_fit_stacked_masked if ragged else fc._lstm_fit_stacked
+
+    class Counted:                            # records each dispatch's size
+        lower = real.lower
+
+        def __call__(self, *a):
+            calls.append(len(a[2]))
+            return real(*a)
+    monkeypatch.setattr(fc, real.__name__, Counted())
+    whole = fit(None)
+    assert calls == [n]
+    calls.clear()
+    split = fit(2**20)
+    assert len(calls) > 1 and sum(calls) == n and len(set(calls[:-1])) == 1
+    for a, b in zip(whole, split):
+        np.testing.assert_allclose(a.last_losses, b.last_losses,
+                                   rtol=1e-6, atol=1e-7)
+        for k in a.params:
+            np.testing.assert_allclose(a.params[k], b.params[k],
+                                       rtol=1e-6, atol=1e-7)
+
+
 def test_batch_refit_heterogeneous_archs_fall_back(base):
     """Architecturally heterogeneous model sets still can't stack ->
     sequential fallback with identical bookkeeping."""
@@ -353,6 +424,106 @@ def test_failed_async_refit_does_not_wedge_the_plane(base):
     plane.maybe_update(1e4)
     assert plane.flush_updates()
     assert any(e.get("batched") for e in plane.refit_log)
+    plane.shutdown()
+
+
+def _raise_device_lost(*_):
+    raise RuntimeError("device lost")
+
+
+@pytest.mark.parametrize("path", ["fused", "per_shard", "device"])
+def test_runtime_forecast_failure_is_reactive_and_counted(base, path):
+    """A forecast dispatch that fails while it runs leaves every target on
+    the reactive path and is counted in degraded_stats(), on the fused and
+    per-shard dispatches of a shared model and on the device engine."""
+    traces, models = base
+    if path == "device":
+        plane = ShardedControlPlane(CFG, _specs(models), n_shards=2,
+                                    device_mesh=1)
+        plane._engine._fwd = _raise_device_lost
+    else:
+        shared = copy.deepcopy(next(iter(models.values())))
+        shared.predict_batch = _raise_device_lost
+        plane = ShardedControlPlane(
+            CFG, [TargetSpec(z, ThresholdPolicy(100.0, 1)) for z in traces],
+            model=shared, n_shards=2, coalesce_dispatch=(path == "fused"))
+    window = next(iter(models.values())).window
+    n_ticks = 12
+    for k in range(120, 120 + n_ticks):
+        t = 15.0 * (k - 119)
+        for z in traces:
+            plane.observe(z, Snapshot(t, traces[z][k]))
+        res = plane.control_step(t, 16, 2)
+        assert not any(res[z].predicted for z in traces)
+    # one failed dispatch per tick with candidates (per shard off the gang)
+    per_tick = len(plane.shards) if path == "per_shard" else 1
+    stats = plane.degraded_stats()
+    assert stats["forecast_errors"] == per_tick * (n_ticks - window)
+    assert stats["refit_failures"] == 0
+    assert plane.faults.last_error == "RuntimeError: device lost"
+    plane.shutdown()
+
+
+def test_program_fault_propagates_instead_of_going_reactive(base):
+    """A forecast program that cannot be built is a fault in the program:
+    the tick raises it and nothing is counted as a reactive fallback."""
+    traces, models = base
+    shared = copy.deepcopy(next(iter(models.values())))
+
+    def unbuildable(recents):
+        raise ProgramFault("NotImplementedError while building forward")
+    shared.predict_batch = unbuildable
+    plane = ShardedControlPlane(
+        CFG, [TargetSpec(z, ThresholdPolicy(100.0, 1)) for z in traces],
+        model=shared, n_shards=2)
+    with pytest.raises(ProgramFault):
+        for k in range(120, 130):
+            t = 15.0 * (k - 119)
+            for z in traces:
+                plane.observe(z, Snapshot(t, traces[z][k]))
+            plane.control_step(t, 16, 2)
+    assert plane.degraded_stats()["forecast_errors"] == 0
+    plane.shutdown()
+
+
+def test_staged_build_failure_is_a_program_fault():
+    """``Staged`` builds once per argument signature and reports a failed
+    trace/lower/compile as ProgramFault (the cause chained)."""
+    import jax
+    import jax.numpy as jnp
+
+    bad = Staged(jax.jit(lambda x: x @ jnp.ones((3, 3))))
+    with pytest.raises(ProgramFault) as info:
+        bad(np.ones((2, 2), np.float32))
+    assert isinstance(info.value.__cause__, TypeError)
+    good = Staged(jax.jit(lambda x: 2 * x))
+    x = np.arange(3, dtype=np.float32)
+    np.testing.assert_array_equal(good(x), 2 * x)
+    np.testing.assert_array_equal(good(x + 1), 2 * (x + 1))
+    assert len(good.executables()) == 1
+    good(np.ones(4, np.float32))
+    assert len(good.executables()) == 2
+
+
+def test_failed_refit_is_counted(base):
+    """A refit whose compute raises on the worker is dropped and counted
+    in degraded_stats(), with the failure's text kept."""
+    traces, models = base
+    plane = ShardedControlPlane(CFG, _specs(models), n_shards=2,
+                                updater=Updater(UpdatePolicy.FINETUNE),
+                                async_ticks=True)
+
+    class _Boom:
+        t = 0.0
+        batched = False
+        def compute(self):
+            raise RuntimeError("corrupt history")
+    plane._refit = (0.0, plane._pool.submit(_Boom().compute), _Boom())
+    assert plane.flush_updates() is False
+    stats = plane.degraded_stats()
+    assert stats["refit_failures"] == 1
+    assert stats["forecast_errors"] == 0
+    assert plane.faults.last_error == "RuntimeError: corrupt history"
     plane.shutdown()
 
 

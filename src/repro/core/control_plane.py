@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import obs
 from repro.core.evaluator import EvalResult
 from repro.core.faults import FaultLog
 from repro.core.forecaster import (LSTMForecaster, forward_stacked_staged,
@@ -367,10 +368,13 @@ def predict_from_stack(cache, idx, wins, m0, n_total: int,
     z = transform_stacked(wins, mean_s, std_s)
     stacked = (cache["stacked"] if len(idx) == n_total
                else jax.tree.map(lambda leaf: leaf[idx], cache["stacked"]))
-    preds = np.asarray(forward_stacked_staged(
-        stacked, jnp.asarray(z),
-        use_pallas=m0.use_pallas if use_pallas is None else use_pallas,
-        arch=m0.arch))
+    with obs.span("ppa.forecast.dispatch"):
+        out = forward_stacked_staged(
+            stacked, jnp.asarray(z),
+            use_pallas=m0.use_pallas if use_pallas is None else use_pallas,
+            arch=m0.arch)
+    with obs.span("ppa.forecast.readback", bytes=out.nbytes):
+        preds = np.asarray(out)
     if m0.residual:
         preds = z[:, -1] + preds
     return preds * std_s + mean_s
@@ -389,6 +393,40 @@ class _Immediate:
 # ======================================================================= #
 #  Columnar shard (the fast path)                                         #
 # ======================================================================= #
+
+
+class _Decision:
+    """One columnar shard's decide in flight, handed from stage to stage:
+    ``final`` is the count as far as the stages so far have taken it."""
+    __slots__ = ("means", "cand", "cur", "maxr", "realised", "conf",
+                 "predicted", "key", "final")
+
+
+def decide_columnar(t, jobs) -> list:
+    """Decide for columnar shards stage by stage: each stage runs for
+    every shard under one span.  ``jobs`` holds ``(shard, state, preds,
+    max_r, cur_r, stale)``; returns each shard's tick record."""
+    shards = [job[0] for job in jobs]
+    stales = [job[5] for job in jobs]
+    with obs.span("ppa.decide.evaluate"):
+        ds = [shard.evaluate(state, preds, max_r, cur_r)
+              for shard, state, preds, max_r, cur_r, _ in jobs]
+    with obs.span("ppa.decide.stabilise"):
+        for shard, d in zip(shards, ds):
+            shard.stabilise(t, d)
+    held = [i for i, stale in enumerate(stales) if stale is not None]
+    if held:
+        with obs.span("ppa.decide.degrade"):
+            for i in held:
+                shards[i].degrade(ds[i], stales[i])
+    guarded = [i for i, shard in enumerate(shards) if shard._grd is not None]
+    if guarded:
+        with obs.span("ppa.decide.guard"):
+            for i in guarded:
+                shards[i].guard(ds[i], stales[i])
+    with obs.span("ppa.decide.record"):
+        return [shard.record(t, d, stale)
+                for shard, d, stale in zip(shards, ds, stales)]
 
 
 class _VecShard:
@@ -615,20 +653,30 @@ class _VecShard:
         objects elementwise (property-tested in tests/test_sharded_plane.py
         and tests/test_columnar.py).  ``stale`` rows hold their current
         replica count and idle their guardrail (the columnar twin of
-        ``stage_degrade`` + the guard's stale skip)."""
+        ``stage_degrade`` + the guard's stale skip).  One shard's case of
+        ``decide_columnar``."""
+        return decide_columnar(
+            t, [(self, state, preds, max_r, cur_r, stale)])[0]
+
+    def evaluate(self, state, preds, max_r, cur_r) -> "_Decision":
+        """Decide's first stage: the key metric each target decides on
+        (its forecast where one is trusted, else its last sample) and the
+        policy's count for it, clamped to the bound."""
         ring, count = state
         means, stds, bayes, cand = preds
         k = self.cfg.key_metric_idx
         Zs = len(self.names)
-        cur = self._as_array(cur_r)
-        maxr = self._as_array(max_r)
-        current_key = np.where(count > 0, ring[:, -1, k], 0.0)
+        d = _Decision()
+        d.means, d.cand = means, cand
+        d.cur = cur = self._as_array(cur_r)
+        d.maxr = self._as_array(max_r)
+        d.realised = np.where(count > 0, ring[:, -1, k], 0.0)
         mk = means[:, k]
-        conf = np.ones(Zs, bool)
+        d.conf = np.ones(Zs, bool)
         if bayes and stds is not None:
-            conf[cand] = stds[cand, k] <= self.cfg.confidence_threshold
-        predicted = cand & conf & np.isfinite(mk)
-        key = np.where(predicted, mk, current_key)
+            d.conf[cand] = stds[cand, k] <= self.cfg.confidence_threshold
+        d.predicted = cand & d.conf & np.isfinite(mk)
+        d.key = key = np.where(d.predicted, mk, d.realised)
         # static policies: one evaluate_batch per policy TYPE (the dispatch
         # table built at construction) — elementwise identical to the
         # scalar __call__ each Evaluator would make
@@ -639,26 +687,37 @@ class _VecShard:
             n = np.empty(Zs, np.int64)
             for cls, idx, stacked in self._pol_groups:
                 n[idx] = cls.evaluate_batch(stacked, key[idx], cur[idx])
-        n = np.minimum(n, maxr)
-        # ScaleDownStabilizer, vectorised (shared timestamps per tick):
-        # the ring keeps exactly the entries the old list filter kept
-        # (tt >= t - stabilization_s, current tick included), and the max
-        # is ONE reduction over the live span
+        d.final = np.minimum(n, d.maxr)
+        return d
+
+    def stabilise(self, t, d: "_Decision") -> None:
+        """ScaleDownStabilizer, vectorised (shared timestamps per tick):
+        the ring keeps exactly the entries the old list filter kept
+        (tt >= t - stabilization_s, current tick included), and the max is
+        ONE reduction over the live span."""
+        n = d.final
         maxrec = self._stab_push(t, n)
-        final = np.where(n < cur, np.minimum(maxrec, maxr), n)
-        if stale is not None and stale.any():
-            # degraded hold: never scale on a metric past its TTL — pin
-            # at the last fresh-tick decision (fallback: live count)
-            hold = np.where(self._deg_last >= 0, self._deg_last, cur)
-            final = np.where(stale, hold, final)
+        d.final = np.where(n < d.cur, np.minimum(maxrec, d.maxr), n)
+
+    def degrade(self, d: "_Decision", stale) -> None:
+        """Degraded hold: never scale on a metric past its TTL — pin at the
+        last fresh-tick decision (fallback: live count)."""
+        if stale.any():
+            hold = np.where(self._deg_last >= 0, self._deg_last, d.cur)
+            d.final = np.where(stale, hold, d.final)
             self.stale_held += int(stale.sum())
-        if self._grd is not None:
-            final = self._guard_apply(final, current_key, cur, maxr,
-                                      key, predicted, stale)
+
+    def guard(self, d: "_Decision", stale) -> None:
+        d.final = self._guard_apply(d.final, d.realised, d.cur, d.maxr,
+                                    d.key, d.predicted, stale)
+
+    def record(self, t, d: "_Decision", stale) -> tuple:
+        """The tick's record in the decision log, and the hold anchor."""
+        final = d.final
         self._deg_last = (final.copy() if stale is None
                           else np.where(stale, self._deg_last, final))
-        rec = (t, final, key, predicted, conf, maxr,
-               means if cand.any() else None, cand)
+        rec = (t, final, d.key, d.predicted, d.conf, d.maxr,
+               d.means if d.cand.any() else None, d.cand)
         self.ticks.append(rec)
         return rec
 
@@ -933,11 +992,12 @@ class TickResult(cabc.Mapping):
     the shards' columnar records (building Z dataclasses per tick is the
     single-controller path's dominant host cost at Z >= 10^3)."""
 
-    def __init__(self, plane, per_shard, t):
+    def __init__(self, plane, per_shard, t, tick: int = -1):
         self._plane = plane
         self._per_shard = per_shard          # list of (shard, record)
         self._by_shard = {id(s): rec for s, rec in per_shard}
         self.t = t
+        self.tick = tick                     # the plane's tick number
         self._cache: dict[str, EvalResult] = {}
 
     def __getitem__(self, name: str) -> EvalResult:
@@ -959,14 +1019,15 @@ class TickResult(cabc.Mapping):
         plane target order — the columnar readout: vectorized shards
         contribute their decision column directly (zero per-target
         ``EvalResult`` objects), fallback shards are gathered per name."""
-        out = np.empty(len(self._plane._names), np.int64)
-        for shard, idx in self._plane._shard_rows:
-            rec = self._by_shard[id(shard)]
-            if shard.vectorized:
-                out[idx] = rec[1]
-            else:
-                out[idx] = [rec[n].replicas for n in shard.names]
-        return out
+        with obs.span("ppa.readout", tick=self.tick):
+            out = np.empty(len(self._plane._names), np.int64)
+            for shard, idx in self._plane._shard_rows:
+                rec = self._by_shard[id(shard)]
+                if shard.vectorized:
+                    out[idx] = rec[1]
+                else:
+                    out[idx] = [rec[n].replicas for n in shard.names]
+            return out
 
     def forecasts_array(self) -> tuple[np.ndarray, np.ndarray]:
         """The tick's forecasts in plane target order: ``(means (Z, M)``
@@ -1207,32 +1268,34 @@ class ShardedControlPlane:
         whose staleness clocks must not advance.  Rows addressed to a
         crashed shard are buffered so the failover tick can serve them
         reactively (the shard's own window died with the process)."""
-        if isinstance(values, dict):
-            rows = np.asarray([values[n] for n in self._names], np.float64)
-        else:
-            rows = np.asarray(values, np.float64)
-        if fresh is not None:
-            fresh = np.asarray(fresh, bool)
-        if self._engine is not None:
-            self._engine.push_rows(rows)
-            self._dev_counts += 1
-            self._dev_last[:] = rows
-            if fresh is None:
-                self._dev_last_seen[:] = t
+        with obs.span("ppa.collect", tick=self._ticks_done):
+            if isinstance(values, dict):
+                rows = np.asarray([values[n] for n in self._names],
+                                  np.float64)
             else:
-                self._dev_last_seen[fresh] = t
-            if self._dev_keep_history:
-                for shard, idx in self._shard_rows:
-                    shard.observe_meta_batch(
-                        t, rows[idx],
-                        fresh=None if fresh is None else fresh[idx])
-            return
-        for si, (shard, idx) in enumerate(self._shard_rows):
-            if self._crash_left[si] > 0:
-                self._crash_rows[si] = rows[idx].copy()
-                continue
-            shard.observe_batch(t, rows[idx],
-                                fresh=None if fresh is None else fresh[idx])
+                rows = np.asarray(values, np.float64)
+            if fresh is not None:
+                fresh = np.asarray(fresh, bool)
+            if self._engine is not None:
+                self._engine.push_rows(rows)
+                self._dev_counts += 1
+                self._dev_last[:] = rows
+                if fresh is None:
+                    self._dev_last_seen[:] = t
+                else:
+                    self._dev_last_seen[fresh] = t
+                if self._dev_keep_history:
+                    for shard, idx in self._shard_rows:
+                        shard.observe_meta_batch(
+                            t, rows[idx],
+                            fresh=None if fresh is None else fresh[idx])
+                return
+            for si, (shard, idx) in enumerate(self._shard_rows):
+                if self._crash_left[si] > 0:
+                    self._crash_rows[si] = rows[idx].copy()
+                    continue
+                shard.observe_batch(
+                    t, rows[idx], fresh=None if fresh is None else fresh[idx])
 
     # -------------------------------------------------------- control loop -
     def begin_tick(self, t: float, max_replicas, current_replicas):
@@ -1244,36 +1307,27 @@ class ShardedControlPlane:
         if self._pending is not None:
             raise RuntimeError("previous tick not finished "
                                "(finish_tick barrier missing)")
-        go_async = self._pool is not None and self.async_ticks
-        stall = self._stall_s       # one-shot forecaster stall (chaos)
-        self._stall_s = 0.0
-        wall0 = time.monotonic()    # forecast-deadline anchor
-        if self._engine is not None:
-            # device mode: refresh the device weight caches iff the refit
-            # epoch moved (between ticks, so no in-flight reader), then
-            # snapshot = the immutable current ring buffer + host counts.
-            # Later pushes build NEW device buffers — the double buffer
-            # costs no copy.
-            self._engine.refresh(self._dev_models, self._models_epoch)
-            ring_ref = self._engine.snapshot()
-            counts = self._dev_counts.copy()
-            state = (self._dev_last.copy(), counts)
-            res = self._res
-            stale = None
-            if res is not None and np.isfinite(res.stale_ttl_s):
-                stale = (t - self._dev_last_seen) > res.stale_ttl_s
-            fut = (self._pool.submit(self._stall_then, stall,
-                                     self._engine.forecast, ring_ref,
-                                     counts, stale)
-                   if go_async
-                   else _Immediate(self._stall_then(
-                       stall, self._engine.forecast, ring_ref, counts,
-                       stale)))
-            self._pending = (t, max_replicas, current_replicas, state,
-                             [fut], [stale], wall0)
-            return self
-        states = [shard.snapshot() for shard in self.shards]
-        stales = self._stale_masks(t)
+        tick = self._ticks_done
+        with obs.span("ppa.forecast", tick=tick):
+            go_async = self._pool is not None and self.async_ticks
+            stall = self._stall_s       # one-shot forecaster stall (chaos)
+            self._stall_s = 0.0
+            wall0 = time.monotonic()    # forecast-deadline anchor
+            if self._engine is not None:
+                self._begin_device_tick(t, tick, go_async, stall, wall0,
+                                        max_replicas, current_replicas)
+            else:
+                self._begin_host_tick(t, go_async, stall, wall0,
+                                      max_replicas, current_replicas)
+        return self
+
+    def _begin_host_tick(self, t, go_async, stall, wall0, max_replicas,
+                         current_replicas):
+        """Host mode: snapshot every shard's windows, then one fused
+        forecast for all shards or one per shard."""
+        with obs.span("ppa.forecast.snapshot"):
+            states = [shard.snapshot() for shard in self.shards]
+            stales = self._stale_masks(t)
         if self._fused:
             preps = self._prepare_fused(states, stales)
             fut = (self._pool.submit(self._stall_then, stall,
@@ -1298,7 +1352,36 @@ class ShardedControlPlane:
                                 stall, shard.forecast, state, stale_s)))
         self._pending = (t, max_replicas, current_replicas, states, futs,
                          stales, wall0)
-        return self
+
+    def _begin_device_tick(self, t, tick, go_async, stall, wall0,
+                           max_replicas, current_replicas):
+        """Device mode: refresh the device weight caches iff the refit
+        epoch moved (between ticks, so no in-flight reader), then snapshot
+        = the immutable current ring buffer + host counts.  Later pushes
+        build NEW device buffers — the double buffer costs no copy."""
+        with obs.span("ppa.forecast.install"):
+            self._engine.refresh(self._dev_models, self._models_epoch)
+        with obs.span("ppa.forecast.snapshot"):
+            ring_ref = self._engine.snapshot()
+            counts = self._dev_counts.copy()
+            state = (self._dev_last.copy(), counts)
+            res = self._res
+            stale = None
+            if res is not None and np.isfinite(res.stale_ttl_s):
+                stale = (t - self._dev_last_seen) > res.stale_ttl_s
+        args = (stall, self._forecast_device, tick, ring_ref, counts,
+                stale)
+        fut = (self._pool.submit(self._stall_then, *args) if go_async
+               else _Immediate(self._stall_then(*args)))
+        self._pending = (t, max_replicas, current_replicas, state, [fut],
+                         [stale], wall0)
+
+    def _forecast_device(self, tick, ring_ref, counts, stale):
+        """The engine's forecast (launch and readback) under a span that
+        carries the tick it forecasts: in async mode it runs on a worker
+        thread, outside the tick's ``ppa.forecast``."""
+        with obs.span("ppa.forecast.device", tick=tick):
+            return self._engine.forecast(ring_ref, counts, stale)
 
     def finish_tick(self) -> TickResult:
         """The actuation barrier: joins the in-flight forecasts (bounded by
@@ -1308,57 +1391,73 @@ class ShardedControlPlane:
         held) — and installs any finished refit."""
         if self._pending is None:
             raise RuntimeError("no tick in flight (call begin_tick first)")
-        t, max_r, cur_r, states, futs, stales, wall0 = self._pending
-        self._pending = None
-        res = self._res
-        deadline = (res.forecast_deadline_s if res is not None
-                    else float("inf"))
-        if self._engine is not None:
-            # device mode: one joined (Z, M) prediction batch; evaluate
-            # stays the shards' columnar host math, fed a fabricated
-            # 1-row ring so ``ring[:, -1, k]`` still reads the last row
-            last, counts = states
-            out = self._join(futs[0], wall0, deadline)
-            Z = len(self._names)
-            if out is None:
-                self._deg["deadline_skips"] += 1
-                self._deg["deadline_reactive"] += Z
-                means_full = np.full((Z, N_METRICS), np.nan)
-                cand_full = np.zeros(Z, bool)
+        tick = self._ticks_done
+        with obs.span("ppa.decide", tick=tick):
+            t, max_r, cur_r, states, futs, stales, wall0 = self._pending
+            self._pending = None
+            res = self._res
+            deadline = (res.forecast_deadline_s if res is not None
+                        else float("inf"))
+            if self._engine is not None:
+                per_shard = self._decide_device_tick(
+                    t, max_r, cur_r, states, futs[0], stales[0], wall0,
+                    deadline)
             else:
-                means_full, cand_full = out
-            stale_full = stales[0]
-            per_shard = []
-            for (shard, _), idx in zip(self._shard_rows,
-                                       self._shard_cuts):
-                state_s = (last[idx][:, None, :], counts[idx])
-                preds_s = (means_full[idx], None, False, cand_full[idx])
-                rec = shard.decide(
-                    t, state_s, preds_s, _bound_slice(max_r, idx),
-                    _bound_slice(cur_r, idx),
-                    stale=None if stale_full is None else stale_full[idx])
-                per_shard.append((shard, rec))
+                per_shard = self._decide_host_tick(
+                    t, max_r, cur_r, states, futs, stales, wall0, deadline)
             self._ticks_done += 1
-            if res is not None:
-                self._tick_epilogue()
-            self.poll_updates()
-            return TickResult(self, per_shard, t)
-        deadline_hit = False
-        if self._fused:
-            out = self._join(futs[0], wall0, deadline)
-            deadline_hit = out is None
-            preds_list = ([None] * len(self.shards) if deadline_hit
-                          else out)
+            with obs.span("ppa.decide.epilogue"):
+                if res is not None:
+                    self._tick_epilogue()
+                self.poll_updates()
+            return TickResult(self, per_shard, t, tick)
+
+    def _decide_device_tick(self, t, max_r, cur_r, state, fut, stale_full,
+                            wall0, deadline) -> list:
+        """Device mode: one joined (Z, M) prediction batch; evaluate stays
+        the shards' columnar host math, fed a fabricated 1-row ring so
+        ``ring[:, -1, k]`` still reads the last row."""
+        last, counts = state
+        with obs.span("ppa.decide.join"):
+            out = self._join(fut, wall0, deadline)
+        Z = len(self._names)
+        if out is None:
+            self._deg["deadline_skips"] += 1
+            self._deg["deadline_reactive"] += Z
+            means_full = np.full((Z, N_METRICS), np.nan)
+            cand_full = np.zeros(Z, bool)
         else:
-            preds_list = []
-            for si, f in enumerate(futs):
-                if self._crash_left[si] > 0:
-                    preds_list.append(None)   # crash branch below
-                    continue
-                out = self._join(f, wall0, deadline)
-                if out is None:
-                    deadline_hit = True
-                preds_list.append(out)
+            means_full, cand_full = out
+        jobs = [(shard, (last[idx][:, None, :], counts[idx]),
+                 (means_full[idx], None, False, cand_full[idx]),
+                 _bound_slice(max_r, idx), _bound_slice(cur_r, idx),
+                 None if stale_full is None else stale_full[idx])
+                for (shard, _), idx in zip(self._shard_rows,
+                                           self._shard_cuts)]
+        recs = decide_columnar(t, jobs)
+        return [(job[0], rec) for job, rec in zip(jobs, recs)]
+
+    def _decide_host_tick(self, t, max_r, cur_r, states, futs, stales,
+                          wall0, deadline) -> list:
+        """Host mode: join every forecast future, then decide each shard —
+        crashed shards reactively, a missed deadline reactively."""
+        deadline_hit = False
+        with obs.span("ppa.decide.join"):
+            if self._fused:
+                out = self._join(futs[0], wall0, deadline)
+                deadline_hit = out is None
+                preds_list = ([None] * len(self.shards) if deadline_hit
+                              else out)
+            else:
+                preds_list = []
+                for si, f in enumerate(futs):
+                    if self._crash_left[si] > 0:
+                        preds_list.append(None)   # crash branch below
+                        continue
+                    out = self._join(f, wall0, deadline)
+                    if out is None:
+                        deadline_hit = True
+                    preds_list.append(out)
         per_shard = []
         deadline_reactive = 0
         for si, ((shard, idx), state) in enumerate(zip(self._shard_rows,
@@ -1380,11 +1479,7 @@ class ShardedControlPlane:
         if deadline_hit:
             self._deg["deadline_skips"] += 1
             self._deg["deadline_reactive"] += deadline_reactive
-        self._ticks_done += 1
-        if res is not None:
-            self._tick_epilogue()
-        self.poll_updates()
-        return TickResult(self, per_shard, t)
+        return per_shard
 
     # ----------------------------------------------------- degraded mode --
     def _stale_masks(self, t: float):
@@ -1531,6 +1626,47 @@ class ShardedControlPlane:
                 "forecast_errors": self.faults.forecast_errors,
                 "refit_failures": self.faults.refit_failures}
 
+    def tick_stats(self) -> dict:
+        """Cumulative work counters of the tick, counted where the work
+        happens (docs/architecture.md, "Observability"): ``ticks``;
+        the device engine's ``h2d_bytes`` (row uploads) and ``d2h_bytes``
+        (forecast downloads); ``weight_installs`` and ``install_bytes``
+        (stacked weights and scaler stats re-uploaded after a refit);
+        ``program_builds`` and ``build_s`` (the engine's programs built:
+        anything after warm-up is a recompile); ``decision_log_ticks`` and
+        ``decision_log_bytes`` (the ticks the shards' decision logs hold
+        and the array bytes those records keep alive, a view's base
+        counted once).  The engine's counters are 0 on a host plane.  The
+        decision log is measured here, on demand, never per tick."""
+        eng = self._engine
+        progs = eng.programs() if eng is not None else ()
+        log_ticks, log_bytes = self._decision_log()
+        out = {"ticks": self._ticks_done}
+        out.update((k, getattr(eng, k, 0)) for k in (
+            "h2d_bytes", "d2h_bytes", "weight_installs", "install_bytes"))
+        out.update(program_builds=sum(p.builds for p in progs),
+                   build_s=sum(p.build_s for p in progs),
+                   decision_log_ticks=log_ticks,
+                   decision_log_bytes=log_bytes)
+        return out
+
+    def _decision_log(self) -> tuple[int, int]:
+        """Ticks held by the columnar shards' decision logs, and the bytes
+        of the arrays their records keep alive (each base array once)."""
+        ticks, roots = 0, {}
+        for shard in self.shards:
+            if not shard.vectorized:
+                continue
+            ticks = max(ticks, len(shard.ticks))
+            for rec in shard.ticks:
+                for a in rec:
+                    if not isinstance(a, np.ndarray):
+                        continue
+                    while isinstance(a.base, np.ndarray):
+                        a = a.base
+                    roots[id(a)] = a
+        return ticks, sum(a.nbytes for a in roots.values())
+
     # ------------------------------------------------------ fused dispatch -
     def _refresh_fused_cache(self) -> dict:
         """Cache of the globally stacked params + scaler stats for the
@@ -1557,7 +1693,8 @@ class ShardedControlPlane:
         gather — stale windows never reach the device."""
         preps = []
         if self.per_target_models:
-            cache = self._refresh_fused_cache()
+            with obs.span("ppa.forecast.install"):
+                cache = self._refresh_fused_cache()
             for si, (shard, (ring, count), off) in enumerate(
                     zip(self.shards, states, self._offsets)):
                 Zs = len(shard.names)
@@ -1664,12 +1801,14 @@ class ShardedControlPlane:
             if pending is None:
                 return
             wall = time.monotonic()
+            tick = self._ticks_done
             if self._pool is not None and self.async_updates:
-                self._refit = (wall, self._pool.submit(pending.compute),
-                               pending)
+                self._refit = (wall, self._pool.submit(
+                    self._refit_compute, pending, tick), pending)
             else:
-                pending.compute()
-                pending.commit()
+                self._refit_compute(pending, tick)
+                with obs.span("ppa.refit.install", tick=tick):
+                    pending.commit()
                 self._models_epoch += 1
                 self.refit_log.append(
                     {"t": t, "submitted": wall,
@@ -1693,6 +1832,12 @@ class ShardedControlPlane:
             if len(merged) < n_rows:     # updater consumed (cleared) it
                 for h in all_hists:
                     h.clear()
+
+    @staticmethod
+    def _refit_compute(pending, tick: int):
+        """A batch refit's compute (on a worker in async mode)."""
+        with obs.span("ppa.refit.compute", tick=tick):
+            return pending.compute()
 
     def invalidate_models(self):
         """Force a rebuild of the fused stacked-params cache.  Only needed
@@ -1725,7 +1870,8 @@ class ShardedControlPlane:
                  "applied": time.monotonic(), "failed": True,
                  "batched": False, "async": True})
             return False
-        pending.commit()                 # install on the control thread
+        with obs.span("ppa.refit.install", tick=self._ticks_done):
+            pending.commit()             # install on the control thread
         self._models_epoch += 1
         self.refit_log.append(
             {"t": pending.t, "submitted": wall,

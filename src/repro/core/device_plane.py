@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core import obs
 from repro.core.faults import FaultLog, Staged
 from repro.core.forecaster import (ARCH_PARAM_LEAVES, Z_CLIP,
                                    lstm_stack_signature, stack_scaler_stats,
@@ -125,20 +126,24 @@ class DevicePlaneEngine:
         self._stacked = None              # device pytree, leading Zp axis
         self._mean = self._std = None     # device (Zp, M) f32
         self._valid = np.zeros(self.Z, bool)
-        self._push = jax.jit(self._push_fn)
+        self._push = Staged(jax.jit(ppa_ring_push))
         self._push_row = jax.jit(self._push_row_fn)
         self._fwd = Staged(forecast_program(
             self.mesh, self.window, self.residual, self.use_pallas,
             self.arch, self.coalesce))
+        # work counters behind ShardedControlPlane.tick_stats()
+        self.h2d_bytes = 0                # per-tick row uploads
+        self.d2h_bytes = 0                # per-tick forecast downloads
+        self.weight_installs = 0          # refreshes that re-uploaded
+        self.install_bytes = 0
+        self._out_bytes = self._row_buf.nbytes   # the (Zp, M) forecast
+
+    def programs(self) -> tuple[Staged, ...]:
+        """The engine's per-tick device programs (builds are counted by
+        each ``Staged``)."""
+        return self._fwd, self._push
 
     # ----------------------------------------------------- ring updates --
-    @staticmethod
-    def _push_fn(ring, rows):
-        # functional shift: the returned buffer replaces self.ring; any
-        # snapshot reference taken before the push stays valid (this is
-        # the async tick's double buffer, no copy needed)
-        return jnp.concatenate([ring[:, 1:], rows[:, None, :]], axis=1)
-
     @staticmethod
     def _push_row_fn(ring, i, row):
         shifted = jnp.concatenate([ring[i, 1:], row[None, :]], axis=0)
@@ -147,16 +152,19 @@ class DevicePlaneEngine:
     def push_rows(self, rows: np.ndarray):
         """One whole-plane ring shift on device: uploads a single (Zp, M)
         f32 row batch (the tick's only host->device transfer)."""
-        self._row_buf[:self.Z] = rows
-        if self.R == 1:
-            # window-1 ring: the shift is the identity, so the upload IS
-            # the new ring — no shift dispatch (device_put builds a fresh
-            # buffer, so earlier snapshots stay valid)
-            self.ring = jax.device_put(
-                self._row_buf[:, None, :], self._s_ring)
-            return
-        dev_rows = jax.device_put(self._row_buf, self._s_rows)
-        self.ring = self._push(self.ring, dev_rows)
+        nbytes = self._row_buf.nbytes
+        with obs.span("ppa.collect.upload", bytes=nbytes):
+            self._row_buf[:self.Z] = rows
+            self.h2d_bytes += nbytes
+            if self.R == 1:
+                # window-1 ring: the shift is the identity, so the upload
+                # IS the new ring — no shift dispatch (device_put builds a
+                # fresh buffer, so earlier snapshots stay valid)
+                self.ring = jax.device_put(
+                    self._row_buf[:, None, :], self._s_ring)
+                return
+            dev_rows = jax.device_put(self._row_buf, self._s_rows)
+            self.ring = self._push(self.ring, dev_rows)
 
     def push_row(self, i: int, row: np.ndarray):
         """Single-target observe (the scalar ``observe`` API)."""
@@ -195,6 +203,9 @@ class DevicePlaneEngine:
         self._mean = jax.device_put(mean_p, self._s_rows)
         self._std = jax.device_put(std_p, self._s_rows)
         self.epoch = epoch
+        self.weight_installs += 1
+        self.install_bytes += (sum(b.nbytes for b in stacked_np.values())
+                               + mean_p.nbytes + std_p.nbytes)
 
     def _s_leaf(self, leaf: np.ndarray) -> NamedSharding:
         return NamedSharding(
@@ -223,27 +234,42 @@ class DevicePlaneEngine:
         if not cand.any():
             return np.full((self.Z, N_METRICS), np.nan, np.float32), cand
         try:
-            out = self._fwd(self._stacked, self._mean, self._std, ring_ref)
-            if cand.all():
-                # steady state: every row is a candidate, skip the mask
-                means = np.asarray(out)[:self.Z]
-            else:
-                means = np.full((self.Z, N_METRICS), np.nan, np.float32)
-                means[cand] = np.asarray(out)[:self.Z][cand]
+            with obs.span("ppa.forecast.dispatch"):
+                out = self._fwd(self._stacked, self._mean, self._std,
+                                ring_ref)
+            # one np.asarray waits for the program and copies its output
+            with obs.span("ppa.forecast.readback", bytes=self._out_bytes):
+                host = np.asarray(out)
+                if cand.all():
+                    # steady state: every row is a candidate, skip the mask
+                    means = host[:self.Z]
+                else:
+                    means = np.full((self.Z, N_METRICS), np.nan, np.float32)
+                    means[cand] = host[:self.Z][cand]
         except Exception as e:
             # robust: a failed gang dispatch -> every target reactive,
             # counted; a program that fails to build raises ProgramFault
             self.faults.forecast_failed(e)
             return np.full((self.Z, N_METRICS), np.nan, np.float32), \
                 np.zeros(self.Z, bool)
+        self.d2h_bytes += host.nbytes
         return means, cand
+
+
+def ppa_ring_push(ring, rows):
+    """The engine's ring shift (module ``jit_ppa_ring_push``): drop the
+    oldest row of every target, append ``rows``.  Functional: the returned
+    buffer replaces the ring, and a snapshot taken before the push stays
+    valid (the async tick's double buffer, no copy needed)."""
+    return jnp.concatenate([ring[:, 1:], rows[:, None, :]], axis=1)
 
 
 def forecast_program(mesh, window: int, residual: bool, use_pallas: bool,
                      arch: str, coalesce: bool):
     """The engine's jitted forecast program: ``(stacked, mean, std, ring)``
     with a leading padded target axis on each -> ``(Zp, M)`` forecasts in
-    metric units (standardise, stacked forward, residual, inverse)."""
+    metric units (standardise, stacked forward, residual, inverse).  Its
+    module is ``jit_ppa_forecast`` in a device trace."""
     W = window
     rows = (P(CONTROL_AXIS), P(CONTROL_AXIS))
 
@@ -256,7 +282,7 @@ def forecast_program(mesh, window: int, residual: bool, use_pallas: bool,
         net_fn = jax.shard_map(net_fn, mesh=mesh, in_specs=rows,
                                out_specs=P(CONTROL_AXIS), check_vma=False)
 
-    def body(stacked, mean, std, ring):
+    def ppa_forecast(stacked, mean, std, ring):
         win = ring[:, -W:, :]
         z = jnp.clip((win - mean[:, None, :]) / std[:, None, :],
                      -Z_CLIP, Z_CLIP)
@@ -268,7 +294,7 @@ def forecast_program(mesh, window: int, residual: bool, use_pallas: bool,
     if coalesce:
         # gang dispatch: ONE program, GSPMD partitions the Z axis over the
         # mesh following the argument shardings
-        return jax.jit(body)
+        return jax.jit(ppa_forecast)
     # per-shard dispatch: shard_map runs the block program per device
     # (PartitionSpecs shorter than an array's rank replicate the trailing
     # dims; the stacked-params dict takes P('shards') as a pytree prefix).
@@ -276,8 +302,8 @@ def forecast_program(mesh, window: int, residual: bool, use_pallas: bool,
     # so there is no varying-axes typing to check — and the Pallas
     # kernels' out_shapes carry none, which check_vma would refuse.
     return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=rows + rows, out_specs=P(CONTROL_AXIS),
-        check_vma=False))
+        ppa_forecast, mesh=mesh, in_specs=rows + rows,
+        out_specs=P(CONTROL_AXIS), check_vma=False))
 
 
 def engine_for_plane(plane, device_mesh, coalesce_dispatch: bool,

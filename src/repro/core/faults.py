@@ -14,9 +14,12 @@ and raises ``ProgramFault`` when that step fails; the plane lets
 from __future__ import annotations
 
 import threading
+import time
 
 import jax
 import numpy as np
+
+from repro.core import obs
 
 
 class ProgramFault(RuntimeError):
@@ -28,11 +31,16 @@ class Staged:
     its first execution for each argument signature.  A failed build
     raises ``ProgramFault`` chained to the cause; a failure while the
     compiled program runs raises as it is.  Arguments must be concrete
-    arrays: the callers run it outside any trace."""
+    arrays: the callers run it outside any trace.  ``builds`` and
+    ``build_s`` count the builds that succeeded and their seconds; each
+    build is a ``ppa.build`` span."""
 
     def __init__(self, jitted):
         self._jitted = jitted
+        self.name = getattr(jitted, "__name__", str(jitted))
         self._exe: dict = {}
+        self.builds = 0
+        self.build_s = 0.0
 
     def __call__(self, *args, **static):
         leaves, tree = jax.tree.flatten(args)
@@ -42,13 +50,16 @@ class Staged:
                       getattr(x, "sharding", None)) for x in leaves))
         exe = self._exe.get(key)
         if exe is None:
-            try:
-                exe = self._jitted.lower(*args, **static).compile()
-            except Exception as e:
-                raise ProgramFault(
-                    f"{type(e).__name__} while building "
-                    f"{getattr(self._jitted, '__name__', self._jitted)}: "
-                    f"{e}") from e
+            t0 = time.perf_counter()
+            with obs.span("ppa.build", program=self.name):
+                try:
+                    exe = self._jitted.lower(*args, **static).compile()
+                except Exception as e:
+                    raise ProgramFault(
+                        f"{type(e).__name__} while building {self.name}: "
+                        f"{e}") from e
+            self.builds += 1
+            self.build_s += time.perf_counter() - t0
             self._exe[key] = exe
         return exe(*args)
 

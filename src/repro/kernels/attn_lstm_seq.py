@@ -194,6 +194,7 @@ def _attn_seq_pallas(Wx1, Wh1, b1, Wa, Wx2, Wh2, b2, Wo, bo, xs,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="attn_lstm_seq",
     )(xs, Wx1, Wh1, b1.reshape(1, -1), Wa, Wx2, Wh2, b2.reshape(1, -1), Wo,
       bo.reshape(1, -1))
     return out[:B]
@@ -235,6 +236,7 @@ def _attn_seq_stacked_pallas(Wx1, Wh1, b1, Wa, Wx2, Wh2, b2, Wo, bo, xs,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="attn_lstm_seq_stacked",
     )(xs, Wx1, Wh1, b1, Wa, Wx2, Wh2, b2, Wo, bo)
     return out[:Z]
 

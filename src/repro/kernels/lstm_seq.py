@@ -157,6 +157,7 @@ def _seq_pallas(Wx, Wh, b, Wo, bo, xs, *, block_b, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="lstm_seq",
     )(xs, Wx, Wh, b.reshape(1, -1), Wo, bo.reshape(1, -1))
     return out[:B]
 
@@ -190,6 +191,7 @@ def _seq_stacked_pallas(Wx, Wh, b, Wo, bo, xs, *, block_b, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="lstm_seq_stacked",
     )(xs, Wx, Wh, b, Wo, bo)
     return out[:Z]
 

@@ -9,9 +9,9 @@ This module moves the forecast half of the tick onto a JAX device mesh:
 * **mesh** — one physical axis ``('shards',)`` over D local devices
   (``distributed.sharding.control_mesh``); the plane's Z-target axis is
   partitioned over it with ``NamedSharding``/``PartitionSpec``.
-* **device-resident state** — the metric ring (Zp, R, M) f32, the stacked
-  LSTM weight pytree, and the stacked scaler stats live on the mesh
-  BETWEEN ticks.  Per tick the host uploads one (Zp, M) row batch and
+* **device-resident state** — the metric ring (Zp, R, M) f32, the
+  forecast's weight operands, and the stacked scaler stats live on the
+  mesh BETWEEN ticks.  Per tick the host uploads one (Zp, M) row batch and
   downloads one (Zp, M) prediction batch; the ring shifts in place on
   device (``jnp`` functional update — the old buffer stays valid, which
   is exactly the double-buffer snapshot the async tick needs for free).
@@ -19,9 +19,18 @@ This module moves the forecast half of the tick onto a JAX device mesh:
   plane into ONE jitted program and lets GSPMD partition it over the mesh;
   ``False`` routes the per-shard path through ``jax.shard_map`` so each
   device runs its own block program (the multi-device deployment shape).
-* **invalidate-on-refit-commit** — stacked weights/scalers re-stack and
-  re-upload only when the plane's refit epoch moves (the same epoch the
-  fused host cache keys on), never per tick.
+* **install form** — ``refresh`` installs the weights in the form the
+  forecast program reads (``forecaster.stacked_operands``): for the fused
+  LSTM kernel at window 1 one f32 row per target (``kernels/lstm_seq.py``
+  ``stacked_form``) of 128-lane-aligned blocks, whose default TPU layout
+  is the one the Mosaic call reads (stacked 3-D leaves default to a
+  target-minor layout, which XLA would relay out on every tick), without
+  ``Wh`` (h0 = 0 makes ``h @ Wh`` zero).  A tick then moves no weight
+  bytes but the kernel's own reads.  Past window 1, and for the attention
+  kernel, the leaves are installed as they are.
+* **invalidate-on-refit-commit** — the installed weights and scalers are
+  rebuilt and re-uploaded only when the plane's refit epoch moves (the
+  same epoch the fused host cache keys on), never per tick.
 
 Bitwise device-count invariance: every per-target computation here is
 row-independent (batched GEMV per target, no cross-target reductions), so
@@ -52,9 +61,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import obs
 from repro.core.faults import FaultLog, Staged
-from repro.core.forecaster import (ARCH_PARAM_LEAVES, Z_CLIP,
-                                   lstm_stack_signature, stack_scaler_stats,
-                                   stacked_forward)
+from repro.core.forecaster import (Z_CLIP, lstm_stack_signature,
+                                   stack_scaler_stats, stacked_forward,
+                                   stacked_operands)
 from repro.core.metrics import N_METRICS
 from repro.distributed.sharding import CONTROL_AXIS, control_mesh
 
@@ -85,7 +94,8 @@ class DevicePlaneEngine:
     The plane (core/control_plane.py) keeps owning collect / evaluate /
     actuate on host numpy; this engine owns exactly the state that used to
     cross the host-device boundary every tick: the metric ring, the
-    stacked per-target LSTM params and the stacked scaler stats.
+    per-target weights (installed in the forecast kernel's form) and the
+    stacked scaler stats.
 
     The engine computes predictions for ALL rows and the plane masks
     non-candidates with NaN on host — a host-side candidate gather would
@@ -110,7 +120,6 @@ class DevicePlaneEngine:
         self.residual = bool(residual)
         self.use_pallas = bool(use_pallas)
         self.arch = str(arch)
-        self.param_leaves = ARCH_PARAM_LEAVES[self.arch]
         self.R = int(ring_rows if ring_rows is not None
                      else max(self.window + 1, 8))
         self.coalesce = bool(coalesce_dispatch)
@@ -123,7 +132,7 @@ class DevicePlaneEngine:
         # beyond Z are never candidates, so zeros are fine)
         self._row_buf = np.zeros((self.Zp, N_METRICS), np.float32)
         self.epoch: int | None = None     # refit epoch of the device caches
-        self._stacked = None              # device pytree, leading Zp axis
+        self._stacked = None              # installed operands, leading Zp
         self._mean = self._std = None     # device (Zp, M) f32
         self._valid = np.zeros(self.Z, bool)
         self._push = Staged(jax.jit(ppa_ring_push))
@@ -178,33 +187,40 @@ class DevicePlaneEngine:
 
     # ------------------------------------------------------ weight cache --
     def refresh(self, models, epoch: int):
-        """Re-stack + re-upload params/scaler stats iff the plane's refit
-        epoch moved (invalidate-on-refit-commit).  Runs on the control
-        thread between ticks, so no in-flight forecast can read a
-        half-installed stack."""
+        """Install the forecast's weight operands and scaler stats iff the
+        plane's refit epoch moved (invalidate-on-refit-commit).  The
+        operands are built on the host in the form the forecast program
+        reads (``stacked_operands``): for the fused LSTM kernel at window 1
+        one row per target of 128-lane-aligned blocks with no ``Wh``, so
+        a tick moves no weight bytes but the kernel's own reads.  Runs on
+        the control thread between ticks, so no in-flight forecast can
+        read a half-installed stack."""
         if self.epoch == epoch:
             return
         self._valid = np.array(
             [self._model_ok(m) for m in models], bool)
-        stacked_np = {}
-        for leaf in self.param_leaves:
+
+        def stack(leaf):
             arrs = [np.asarray(m.params[leaf], np.float32) for m in models]
             buf = np.zeros((self.Zp,) + arrs[0].shape, np.float32)
             buf[:self.Z] = np.stack(arrs)
-            stacked_np[leaf] = buf
+            return buf
+
+        operands = stacked_operands(stack, self.window,
+                                    use_pallas=self.use_pallas,
+                                    arch=self.arch, xp=np)
         mean, std = stack_scaler_stats(models)
         mean_p = np.zeros((self.Zp, N_METRICS), np.float32)
         std_p = np.ones((self.Zp, N_METRICS), np.float32)
         mean_p[:self.Z] = mean
         std_p[:self.Z] = std
         self._stacked = jax.tree.map(
-            lambda leaf: jax.device_put(leaf, self._s_leaf(leaf)),
-            stacked_np)
+            lambda leaf: jax.device_put(leaf, self._s_leaf(leaf)), operands)
         self._mean = jax.device_put(mean_p, self._s_rows)
         self._std = jax.device_put(std_p, self._s_rows)
         self.epoch = epoch
         self.weight_installs += 1
-        self.install_bytes += (sum(b.nbytes for b in stacked_np.values())
+        self.install_bytes += (sum(a.nbytes for a in jax.tree.leaves(operands))
                                + mean_p.nbytes + std_p.nbytes)
 
     def _s_leaf(self, leaf: np.ndarray) -> NamedSharding:
@@ -267,9 +283,10 @@ def ppa_ring_push(ring, rows):
 def forecast_program(mesh, window: int, residual: bool, use_pallas: bool,
                      arch: str, coalesce: bool):
     """The engine's jitted forecast program: ``(stacked, mean, std, ring)``
-    with a leading padded target axis on each -> ``(Zp, M)`` forecasts in
-    metric units (standardise, stacked forward, residual, inverse).  Its
-    module is ``jit_ppa_forecast`` in a device trace."""
+    with a leading padded target axis on each, ``stacked`` being the
+    installed weight operands (``stacked_operands``) -> ``(Zp, M)``
+    forecasts in metric units (standardise, stacked forward, residual,
+    inverse).  Its module is ``jit_ppa_forecast`` in a device trace."""
     W = window
     rows = (P(CONTROL_AXIS), P(CONTROL_AXIS))
 

@@ -446,42 +446,55 @@ def stack_scaler_stats(models) -> tuple[np.ndarray, np.ndarray]:
             np.stack([m.scaler.std for m in models]))
 
 
-def stacked_forward(stacked_params, xs, *, use_pallas: bool = False,
+def stacked_operands(leaf, window: int, *, use_pallas: bool = False,
+                     arch: str = "lstm", xp=jnp):
+    """The weight operands ``stacked_forward`` reads at ``window``, from
+    stacked per-target leaves: ``leaf(name)`` gives the leaf of that name
+    with its leading target axis.  The fused LSTM kernel reads its
+    ``stacked_form`` (``kernels/lstm_seq.py``: at window 1 one row of
+    128-lane-aligned blocks per target and no ``Wh``); every other
+    forward reads the leaves as they are.  ``xp`` is ``np`` where the operands are built on the
+    host (the device plane's install) and ``jnp`` inside a program."""
+    if arch == "lstm" and use_pallas:
+        from repro.kernels.lstm_seq import stacked_form
+        return stacked_form(leaf, window, xp=xp)
+    return {name: leaf(name) for name in ARCH_PARAM_LEAVES[arch]}
+
+
+def stacked_forward(operands, xs, *, use_pallas: bool = False,
                     arch: str = "lstm"):
-    """Pure (unjitted) stacked per-target forward body: pytree with
-    leading target axis Z, xs (Z, W, M) -> (Z, M).  Split out of
-    ``_lstm_forward_stacked`` so callers that build their own dispatch
-    wrapper — the device plane's ``jax.jit``/``shard_map`` programs
-    (core/device_plane.py) — trace the SAME math instead of nesting jits.
+    """Pure (unjitted) stacked per-target forward body: the operands of
+    ``stacked_operands`` (leading target axis Z), xs (Z, W, M) -> (Z, M).
+    Split out of ``_lstm_forward_stacked`` so callers that build their own
+    dispatch wrapper — the device plane's ``jax.jit``/``shard_map``
+    programs (core/device_plane.py), which install the operands once per
+    refit epoch — trace the SAME math instead of nesting jits.
     The Pallas path routes through ``ops.lstm_seq_stacked_local`` /
     ``ops.attn_lstm_seq_stacked_local`` (the shard_map-compatible entries:
     local block shapes, no jit boundary).
 
-    The XLA path elides the first timestep's recurrent terms: with
+    Both LSTM paths elide the first timestep's recurrent terms: with
     h0 = c0 = 0 the ``h @ Wh`` matmul and the ``sigmoid(f) * c`` forget
     term are exactly zero, so step 1 reduces to the input projection —
     at window=1 (the forecaster default) that removes the dominant
-    batched GEMV from the whole dispatch.  The elision is value-exact
-    (identical at window=1; later steps may differ from the scan-only
-    graph at f32 fusion-rounding level, within forecast parity
-    tolerances).  The training path (``lstm_forward``) keeps the plain
-    scan so fit losses and gradients are untouched."""
+    batched GEMV from the whole dispatch, and the kernel's operands hold
+    no ``Wh``.  The elision is value-exact (identical at window=1; later
+    steps of the XLA path may differ from the scan-only graph at f32
+    fusion-rounding level, within forecast parity tolerances).  The
+    training path (``lstm_forward``) keeps the plain scan so fit losses
+    and gradients are untouched."""
     if arch == "attn":
         if use_pallas:
             from repro.kernels import ops
             return ops.attn_lstm_seq_stacked_local(
-                stacked_params["Wx1"], stacked_params["Wh1"],
-                stacked_params["b1"], stacked_params["Wa"],
-                stacked_params["Wx2"], stacked_params["Wh2"],
-                stacked_params["b2"], stacked_params["Wo"],
-                stacked_params["bo"], xs)
+                operands["Wx1"], operands["Wh1"], operands["b1"],
+                operands["Wa"], operands["Wx2"], operands["Wh2"],
+                operands["b2"], operands["Wo"], operands["bo"], xs)
         return jax.vmap(lambda p, x: _attn_body(p, x[None])[0])(
-            stacked_params, xs)
+            operands, xs)
     if use_pallas:
         from repro.kernels import ops
-        return ops.lstm_seq_stacked_local(
-            stacked_params["Wx"], stacked_params["Wh"], stacked_params["b"],
-            stacked_params["Wo"], stacked_params["bo"], xs)
+        return ops.lstm_seq_stacked_local(operands, xs)
 
     def fwd(p, x):
         gates = x[0] @ p["Wx"] + p["b"]
@@ -494,7 +507,7 @@ def stacked_forward(stacked_params, xs, *, use_pallas: bool = False,
                 return lstm_cell(p, h, c, xw), None
             (h, c), _ = jax.lax.scan(step, (h, c), x[1:])
         return jax.nn.relu(h) @ p["Wo"] + p["bo"]
-    return jax.vmap(fwd)(stacked_params, xs)
+    return jax.vmap(fwd)(operands, xs)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "arch"))
@@ -502,11 +515,12 @@ def _lstm_forward_stacked(stacked_params, xs, *, use_pallas: bool = False,
                           arch: str = "lstm"):
     """stacked_params: pytree with leading target axis Z; xs (Z, W, M) ->
     (Z, M).  One device dispatch for all Z targets: the Pallas path is the
-    fused block-batched sequence kernel (per-row weights, per-row GEMV
-    gate matmuls, W-step fori_loop in VMEM scratch); the XLA path vmaps
-    the scan forward."""
-    return stacked_forward(stacked_params, xs, use_pallas=use_pallas,
-                           arch=arch)
+    fused block-batched sequence kernel (per-row weights in the stacked
+    form, built here per call; W-step fori_loop in VMEM scratch); the XLA
+    path vmaps the scan forward."""
+    operands = stacked_operands(stacked_params.__getitem__, xs.shape[1],
+                                use_pallas=use_pallas, arch=arch)
+    return stacked_forward(operands, xs, use_pallas=use_pallas, arch=arch)
 
 
 forward_stacked_staged = Staged(_lstm_forward_stacked)

@@ -18,9 +18,14 @@ Two layouts:
   gate matmuls are plain (B, M)@(M, 4H) GEMMs on the MXU (the shared-model
   ``predict_batch`` and every fit-path forward);
 * ``lstm_seq_stacked``  — per-row weights with a leading target axis:
-  xs (Z, W, M), every param leaf (Z, ...) -> (Z, n_out); the gate matmuls
-  are per-row GEMVs (``row_matvec``: a VPU multiply and a reduce over K)
-  — Z independently trained per-target LSTMs in ONE dispatch.
+  xs (Z, W, M), every param leaf (Z, ...) -> (Z, n_out) — Z independently
+  trained per-target LSTMs in ONE dispatch.  At window 1 the kernel reads
+  the weights in their *stacked form* (``stacked_form``): one f32 row per
+  target holding Wx, b, Wo and bo in 128-lane-aligned blocks, and no Wh;
+  past window 1 it reads the leaves as they are and its gate matmuls are
+  per-row GEMVs (``row_matvec``: a VPU multiply and a reduce over K).
+  The device plane installs these operands once per refit epoch; this
+  entry builds them per call.
 
 Both are differentiable via ``jax.custom_vjp`` with a checkpoint-style
 backward: the forward saves only its inputs and the backward replays the
@@ -32,7 +37,9 @@ tests vs ``ref.py``); on TPU they compile to Mosaic.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -118,6 +125,118 @@ def _seq_stacked_kernel(xs_ref, wx_ref, wh_ref, b_ref, wo_ref, bo_ref,
                     ).astype(out_ref.dtype)
 
 
+def _seq_form_kernel(xs_ref, theta_ref, out_ref, *, hidden, n_out):
+    """Window-1 block in the stacked form: xs (bb, 1, M), theta (bb, P).
+    The state starts at zero, so there is no recurrent term and no forget
+    term: c = i * g.  Each per-row GEMV is a sum over K of an aligned
+    weight row slice scaled by one input column."""
+    n_in = xs_ref.shape[-1]
+    H, G = hidden, 4 * hidden
+    g_stride, h_stride = _lanes(G), _lanes(H)
+    b, wo, bo, _ = _form_layout(n_in, H, n_out)
+    x = xs_ref[:, 0, :].astype(jnp.float32)
+    gates = x[:, 0:1] * theta_ref[:, 0:G]
+    for k in range(1, n_in):
+        gates = gates + x[:, k:k + 1] * theta_ref[:, k * g_stride:
+                                                  k * g_stride + G]
+    gates = gates + theta_ref[:, b:b + G]
+    i = jax.nn.sigmoid(gates[:, 0:H])
+    g = jnp.tanh(gates[:, 2 * H:3 * H])
+    o = jax.nn.sigmoid(gates[:, 3 * H:G])
+    hr = jax.nn.relu(o * jnp.tanh(i * g))
+    # head: Wo lies as (n_out, H), so output j is a reduce over lanes
+    lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    out = theta_ref[:, bo:bo + n_out]
+    for j in range(n_out):
+        w = theta_ref[:, wo + j * h_stride:wo + j * h_stride + H]
+        col = jnp.sum(hr * w, axis=1, keepdims=True)
+        out = out + jnp.where(lane == j, col, 0.0)
+    out_ref[...] = out.astype(out_ref.dtype)
+
+
+# --------------------------------------------------------- stacked form ---
+# At window 1 the per-target kernel reads one f32 row per target: Wx as M
+# blocks of 4H, b as one block of 4H, Wo transposed as n_out blocks of H,
+# bo, every block zero-padded to a multiple of 128 lanes, and no Wh: the
+# state starts at zero, so ``h @ Wh`` is exactly zero.  Two reasons:
+# * on a TPU the default layout of a (Z, P) array with P a multiple of 128
+#   is the row-major tiled one the Mosaic call reads, so an installed form
+#   reaches the kernel with no relayout; a stacked 3-D leaf such as Wh
+#   (Z, 50, 200) defaults to the layout with the target axis minor, which
+#   pads least but is not the kernel's, so XLA would copy it every call;
+# * aligned blocks are read without lane rotations: on a v5e the kernel
+#   took 1.5x as long with the leaves packed back to back.
+# Past window 1 the recurrent body reads the leaves as they are.
+
+_LANES = 128
+# bytes of theta in one grid block: double-buffered, with the block's
+# temporaries, well inside the 16 MiB of scoped VMEM of a v5e core
+_BLOCK_BYTES = 2 << 20
+_RECURRENT_BLOCK = 32
+LEAVES = ("Wx", "Wh", "b", "Wo", "bo")
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["theta"], meta_fields=["hidden", "n_out"])
+@dataclasses.dataclass(frozen=True)
+class StackedForm:
+    """Per-target LSTM weights as the window-1 kernel reads them:
+    ``theta`` (Z, P) with one row per target (see ``stacked_form``)."""
+    theta: Any
+    hidden: int
+    n_out: int
+
+
+def _lanes(n: int) -> int:
+    return -(-n // _LANES) * _LANES
+
+
+def _form_layout(n_in: int, hidden: int, n_out: int):
+    """Lane offsets of b, Wo and bo in a target's row (Wx is at 0), and
+    the row's width P."""
+    b = n_in * _lanes(4 * hidden)
+    wo = b + _lanes(4 * hidden)
+    bo = wo + n_out * _lanes(hidden)
+    return b, wo, bo, bo + _lanes(n_out)
+
+
+def form_width(n_in: int, hidden: int, n_out: int) -> int:
+    """Lanes P of one target's row in the stacked form."""
+    return _form_layout(n_in, hidden, n_out)[-1]
+
+
+def stacked_form(leaf, window: int, *, xp=jnp):
+    """The stacked kernel's weight operands at ``window``.  ``leaf(name)``
+    gives the leaf of that name with its leading target axes.  At window
+    1 a ``StackedForm``, for which ``Wh`` is never asked for; past window
+    1 the leaves ``LEAVES`` as they are.  ``xp`` is ``jnp`` inside a
+    program and ``np`` for an install built on the host."""
+    if window > 1:
+        return tuple(leaf(name) for name in LEAVES)
+    Wx, b, Wo, bo = leaf("Wx"), leaf("b"), leaf("Wo"), leaf("bo")
+    lead = Wx.shape[:-2]
+    hidden, n_out = Wx.shape[-1] // 4, Wo.shape[-1]
+
+    def blocks(a):
+        # (..., K, N) -> (..., K * lanes(N)): each row zero-padded
+        k, n = a.shape[-2:]
+        pad = xp.zeros(a.shape[:-1] + (_lanes(n) - n,), a.dtype)
+        return xp.concatenate([a, pad], axis=-1).reshape(
+            lead + (k * _lanes(n),))
+
+    theta = xp.concatenate(
+        [blocks(Wx), blocks(b[..., None, :]),
+         blocks(xp.swapaxes(Wo, -1, -2)), blocks(bo[..., None, :])], axis=-1)
+    return StackedForm(theta, hidden, n_out)
+
+
+def _block_rows(width: int) -> int:
+    """Rows of a grid block: the power of two nearest below
+    ``_BLOCK_BYTES`` of theta, at least 8."""
+    rows = max(_BLOCK_BYTES // (4 * width), 8)
+    return 1 << (rows.bit_length() - 1)
+
+
 def _pad_rows(arrs, pad: int):
     if not pad:
         return arrs
@@ -196,6 +315,38 @@ def _seq_stacked_pallas(Wx, Wh, b, Wo, bo, xs, *, block_b, interpret):
     return out[:Z]
 
 
+def _seq_form_pallas(form, xs, *, block_b, interpret):
+    Z, W, M = xs.shape
+    H, n_out = form.hidden, form.n_out
+    theta = form.theta
+    width = theta.shape[-1]
+    if W != 1 or width != form_width(M, H, n_out):
+        raise ValueError(f"a stacked form of width {width} does not fit "
+                         f"M={M}, H={H}, n_out={n_out} at window {W}")
+    if Z == 0:          # empty batch: match the vmap path's contract
+        return jnp.zeros((0, n_out), xs.dtype)
+    block_b = max(min(block_b, Z), 1)
+    pad = (-Z) % block_b
+    xs, theta = _pad_rows([xs, theta], pad)
+    nb = xs.shape[0] // block_b
+    kernel = functools.partial(_seq_form_kernel, hidden=H, n_out=n_out)
+    out = pl.pallas_call(
+        kernel,
+        grid=(nb,),
+        in_specs=[
+            pl.BlockSpec((block_b, W, M), lambda i: (i, 0, 0)),
+            pl.BlockSpec((block_b, width), lambda i: (i, 0)),
+        ],
+        out_specs=pl.BlockSpec((block_b, n_out), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((xs.shape[0], n_out), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="lstm_seq_stacked",
+    )(xs, theta)
+    return out[:Z]
+
+
 # ------------------------------------------------------------- autodiff ---
 # Checkpoint-style custom VJP: forward = the fused kernel, residuals = the
 # raw inputs, backward = jax.vjp over the pure-jnp reference.  Gradients are
@@ -223,15 +374,20 @@ def _lstm_seq_bwd(block_b, interpret, res, g):
 _lstm_seq_vjp.defvjp(_lstm_seq_fwd, _lstm_seq_bwd)
 
 
+def _leaves_form(Wx, Wh, b, Wo, bo, xs):
+    leaves = dict(zip(LEAVES, (Wx, Wh, b, Wo, bo)))
+    return stacked_form(leaves.__getitem__, xs.shape[1])
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def _lstm_seq_stacked_vjp(Wx, Wh, b, Wo, bo, xs, block_b, interpret):
-    return _seq_stacked_pallas(Wx, Wh, b, Wo, bo, xs, block_b=block_b,
-                               interpret=interpret)
+    return lstm_seq_stacked_form(_leaves_form(Wx, Wh, b, Wo, bo, xs), xs,
+                                 block_b=block_b, interpret=interpret)
 
 
 def _lstm_seq_stacked_fwd(Wx, Wh, b, Wo, bo, xs, block_b, interpret):
-    out = _seq_stacked_pallas(Wx, Wh, b, Wo, bo, xs, block_b=block_b,
-                              interpret=interpret)
+    out = lstm_seq_stacked_form(_leaves_form(Wx, Wh, b, Wo, bo, xs), xs,
+                                block_b=block_b, interpret=interpret)
     return out, (Wx, Wh, b, Wo, bo, xs)
 
 
@@ -252,9 +408,29 @@ def lstm_seq(Wx, Wh, b, Wo, bo, xs, *, block_b: int = 128,
     return _lstm_seq_vjp(Wx, Wh, b, Wo, bo, xs, block_b, interpret)
 
 
-def lstm_seq_stacked(Wx, Wh, b, Wo, bo, xs, *, block_b: int = 32,
+def lstm_seq_stacked(Wx, Wh, b, Wo, bo, xs, *, block_b: int | None = None,
                      interpret: bool = False):
     """Per-target layout: xs (Z, W, M) and a leading Z axis on every weight
     leaf -> (Z, n_out).  Z independently parameterised LSTMs answered by
-    ONE fused kernel (per-row GEMV gate matmuls per block)."""
+    ONE fused kernel (per-row GEMV gate matmuls per block); the leaves are
+    put in the stacked form on each call (``lstm_seq_stacked_form``).
+    Differentiable (checkpoint-style custom VJP)."""
     return _lstm_seq_stacked_vjp(Wx, Wh, b, Wo, bo, xs, block_b, interpret)
+
+
+def lstm_seq_stacked_form(form, xs, *, block_b: int | None = None,
+                          interpret: bool = False):
+    """``lstm_seq_stacked`` over the weight operands of ``stacked_form``
+    (the device plane installs them once per refit epoch): xs (Z, W, M)
+    -> (Z, n_out).  The window's static shape picks the body: at window 1
+    the stacked form, with no recurrent term; past it the leaves as they
+    are.  ``block_b`` rows per grid block, by default ``_BLOCK_BYTES`` of
+    the form at window 1 and ``_RECURRENT_BLOCK`` past it.  Forward
+    only."""
+    if xs.shape[1] > 1:
+        return _seq_stacked_pallas(*form, xs,
+                                   block_b=block_b or _RECURRENT_BLOCK,
+                                   interpret=interpret)
+    return _seq_form_pallas(
+        form, xs, block_b=block_b or _block_rows(form.theta.shape[-1]),
+        interpret=interpret)

@@ -19,8 +19,10 @@ from repro.kernels.flash_attention import flash_attention as _fa_impl
 from repro.kernels.decode_attention import decode_attention as _da_impl
 from repro.kernels.ssd_scan import ssd_scan as _ssd_impl
 from repro.kernels.lstm_cell import lstm_cell as _lstm_cell_impl
-from repro.kernels.lstm_seq import (lstm_seq as _lseq_impl,
-                                    lstm_seq_stacked as _lseq_stacked_impl)
+from repro.kernels.lstm_seq import (
+    lstm_seq as _lseq_impl,
+    lstm_seq_stacked as _lseq_stacked_impl,
+    lstm_seq_stacked_form as _lseq_form_impl)
 from repro.kernels.attn_lstm_seq import (
     attn_lstm_seq as _aseq_impl,
     attn_lstm_seq_stacked as _aseq_stacked_impl)
@@ -72,21 +74,22 @@ def lstm_seq(Wx, Wh, b, Wo, bo, xs, *, block_b=128):
 
 
 @functools.partial(jax.jit, static_argnames=("block_b",))
-def lstm_seq_stacked(Wx, Wh, b, Wo, bo, xs, *, block_b=32):
+def lstm_seq_stacked(Wx, Wh, b, Wo, bo, xs, *, block_b=None):
     """Fused whole-window forward for Z stacked per-target LSTMs (leading
     Z axis on xs and every weight leaf) — ONE kernel dispatch per tick."""
     return _lseq_stacked_impl(Wx, Wh, b, Wo, bo, xs, block_b=block_b,
                               interpret=_interpret())
 
 
-def lstm_seq_stacked_local(Wx, Wh, b, Wo, bo, xs, *, block_b=32):
-    """Unjitted ``lstm_seq_stacked`` body for callers that own the jit
-    boundary — in particular ``shard_map`` programs (the multi-device
-    control plane, core/device_plane.py), where the kernel must trace on
-    the per-device LOCAL block shapes rather than behind a nested jit.
-    Backend interpret resolution is identical to the jitted wrapper."""
-    return _lseq_stacked_impl(Wx, Wh, b, Wo, bo, xs, block_b=block_b,
-                              interpret=_interpret())
+def lstm_seq_stacked_local(form, xs, *, block_b=None):
+    """Unjitted stacked LSTM forward over weights in the stacked form
+    (``lstm_seq.stacked_form``), for callers that own the jit boundary —
+    in particular ``shard_map`` programs (the multi-device control plane,
+    core/device_plane.py), where the kernel must trace on the per-device
+    LOCAL block shapes rather than behind a nested jit.  Backend interpret
+    resolution is identical to the jitted wrapper."""
+    return _lseq_form_impl(form, xs, block_b=block_b,
+                           interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("block_b",))

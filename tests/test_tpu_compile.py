@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,7 +23,8 @@ from jax.sharding import SingleDeviceSharding
 import repro.kernels.ops as ops
 from repro.core.device_plane import forecast_program
 from repro.core.forecaster import (ARCH_INITS, AttnLSTMForecaster,
-                                   LSTMForecaster, _lstm_fit_stacked)
+                                   LSTMForecaster, _lstm_fit_stacked,
+                                   stacked_operands)
 from repro.core.metrics import N_METRICS as M
 from repro.distributed.sharding import CONTROL_AXIS
 from repro.kernels.attn_lstm_seq import attn_lstm_seq, attn_lstm_seq_stacked
@@ -104,13 +107,33 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_stacked_kernel_with_recurrence_compiles_for_v5e(one_chip, mosaic):
+    """Past window 1 the stacked kernel reads the leaves as they are and
+    adds the recurrent term: compile that body, forward and backward."""
+    params = _params("lstm", (Z,))
+    leaves = [params[k] for k in LSTMForecaster.PARAM_LEAVES]
+    xs = jax.ShapeDtypeStruct((Z, 2, M), jnp.float32)
+    args = _shapes(leaves + [xs], one_chip)
+    text = _compiled_text(lambda *a: ops.lstm_seq_stacked(*a), *args)
+    assert "tpu_custom_call" in text
+    _compiled_text(jax.grad(lambda *a: ops.lstm_seq_stacked(*a).sum(),
+                            argnums=(0, 1)), *args)
+
+
 def _plane_args(arch, Zp, mesh):
+    """The forecast program's arguments as the engine installs them: the
+    weight operands of ``stacked_operands`` and the scaler stats and ring,
+    sharded on the target axis."""
     rows = NamedSharding(mesh, P(CONTROL_AXIS))
-    stacked = {k: jax.ShapeDtypeStruct(
+    params = _params(arch, (Zp,))
+    operands = jax.eval_shape(lambda: stacked_operands(
+        lambda k: jnp.zeros(params[k].shape, params[k].dtype),
+        WINDOWS[arch], use_pallas=True, arch=arch))
+    stacked = jax.tree.map(lambda v: jax.ShapeDtypeStruct(
         v.shape, v.dtype,
         sharding=NamedSharding(mesh, P(CONTROL_AXIS,
-                                       *(None,) * (v.ndim - 1))))
-        for k, v in _params(arch, (Zp,)).items()}
+                                       *(None,) * (v.ndim - 1)))),
+        operands)
     stats = jax.ShapeDtypeStruct((Zp, M), jnp.float32, sharding=rows)
     ring = jax.ShapeDtypeStruct((Zp, WINDOWS[arch], M), jnp.float32,
                                 sharding=rows)
@@ -123,8 +146,14 @@ def test_device_plane_forward_compiles_for_v5e(arch, topo, mosaic):
     forward, residual, inverse) on one chip."""
     mesh = Mesh(np.asarray(topo.devices[:1]), (CONTROL_AXIS,))
     fwd = forecast_program(mesh, WINDOWS[arch], True, True, arch, True)
-    text = fwd.lower(*_plane_args(arch, Z, mesh)).compile().as_text()
+    args = _plane_args(arch, Z, mesh)
+    text = fwd.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    if arch == "lstm":
+        # the installed form's default layout is the one the kernel reads:
+        # no relayout copy of the weights inside the program
+        theta = "f32[%d,%d]" % args[0].theta.shape
+        assert not re.search(re.escape(theta) + r"\S* copy\(", text)
 
 
 @pytest.mark.parametrize("coalesce", [True, False])

@@ -26,7 +26,7 @@ from pathlib import Path  # noqa: E402
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from chipbench.harness import NoChip, drive_cell, judge  # noqa: E402
+from chipbench.harness import BadCell, NoChip, drive_cell, judge  # noqa: E402
 from chipbench.layout import Layout  # noqa: E402
 
 
@@ -53,7 +53,7 @@ def main(argv=None) -> int:
         try:
             run = drive_cell(layout, args.workload, seed, args.seconds,
                              False, time.perf_counter())
-        except NoChip as e:
+        except (NoChip, BadCell) as e:
             print(f"calibrate: {e}", file=sys.stderr)
             return 2
         r = readings(run)

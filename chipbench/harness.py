@@ -39,6 +39,10 @@ class NoChip(RuntimeError):
     """JAX found no TPU, or fewer chips than the cell asks for."""
 
 
+class BadCell(ValueError):
+    """A cell whose configuration does not run on the chips it asks for."""
+
+
 def find_chips(chips: int):
     import jax
     devices = jax.devices()
@@ -90,6 +94,14 @@ class Run:
         self.arch = layout.model(self.config["arch"])
         self.seed = int(seed)
         self.Z = int(self.mix["targets"])
+        # the plane's mesh is the first ``device_mesh`` devices; the cell's
+        # device metrics count its ``chips``: the two have to be one number
+        self.n_chips = int(cell["chips"])
+        if int(self.config["device_mesh"]) != self.n_chips:
+            raise BadCell(
+                f"cell {cell['name']!r} asks for {self.n_chips} chip(s) but "
+                f"its config {cell['config']!r} runs on device_mesh="
+                f"{self.config['device_mesh']}")
         self.tick_s: list[float] = []
         self.window_s = float("nan")
         self.setup_s = float("nan")
@@ -243,7 +255,7 @@ class Run:
         self.check_ticks = sorted(self.sampled)
         self.memory_peak_bytes = max(
             (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-            for d in jax.local_devices())
+            for d in jax.devices()[:self.n_chips])
 
     def release(self):
         """Free the program's state once the window's readings are taken."""
@@ -321,17 +333,16 @@ def to_bf16(x):
     return np.asarray(x).astype(ml_dtypes.bfloat16).astype(np.float64)
 
 
-def drive_cell(layout: Layout, workload: str, seed: int, seconds: float,
-               trace: bool, t_proc0: float, *, require_tpu: bool = True,
-               log=sys.stderr) -> Run:
-    """Set up one cell, drive its window and free the program's state;
-    returns the run with what the window produced.  Raises ``NoChip``
-    before any work when ``require_tpu`` and JAX finds no TPU or too few
-    chips."""
-    cell = layout.cell(workload)
-    run = Run(layout, cell, seed)
+def start(layout: Layout, workload: str, seed: int, *,
+          require_tpu: bool = True,
+          log=sys.stderr) -> tuple[Run, CompileClock]:
+    """The run of one cell before any work is done.  Raises ``BadCell``
+    when the cell's config runs on another number of chips than the cell
+    asks for, and ``NoChip`` when ``require_tpu`` and JAX finds no TPU or
+    too few chips."""
+    run = Run(layout, layout.cell(workload), seed)
     if require_tpu:
-        find_chips(int(cell["chips"]))
+        find_chips(run.n_chips)
     import jax
     src = layout.root / "src"
     if str(src) not in sys.path:
@@ -339,14 +350,24 @@ def drive_cell(layout: Layout, workload: str, seed: int, seconds: float,
     use_compile_cache(layout.root)
     clock = CompileClock()
     run.device = jax.devices()[0]
-    run.n_chips = len(jax.devices())
     if require_tpu:
         run.peaks = layout.peaks(run.device.device_kind)
 
     def say(msg):
         print(f"[{workload} seed={seed}] {msg}", file=log, flush=True)
     run.say = say
+    return run, clock
 
+
+def drive_cell(layout: Layout, workload: str, seed: int, seconds: float,
+               trace: bool, t_proc0: float, *, require_tpu: bool = True,
+               log=sys.stderr) -> Run:
+    """Set up one cell, drive its window and free the program's state;
+    returns the run with what the window produced.  Refuses a cell before
+    any work as ``start`` does."""
+    run, clock = start(layout, workload, seed, require_tpu=require_tpu,
+                       log=log)
+    say = run.say
     run.make_inputs()
     say(f"inputs made at {time.perf_counter() - t_proc0:.3f} s")
     run.build_plane(run.leaves)
@@ -371,8 +392,8 @@ def drive_cell(layout: Layout, workload: str, seed: int, seconds: float,
             f"{sum(gc_ms):.1f}, longest {max(gc_ms, default=0.0):.1f}")
         if trace_dir is not None:
             files = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
-            run.trace = tracing.reduce(tracing.read(files[0])) if files \
-                else None
+            run.trace = tracing.reduce(tracing.read(files[0]),
+                                       run.n_chips) if files else None
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)

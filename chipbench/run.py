@@ -8,8 +8,10 @@ once and prints its result as the last line of standard output.
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics from a profiler trace of the window.  The numbers that
 decide ``correct`` are printed last on standard error and last in the
-result line, each beside its limit.  Without a TPU, or with fewer chips
-than the cell asks for, it exits with code 2 and prints no result.
+result line, each beside its limit.  Without a TPU, with fewer chips
+than the cell asks for, or for a cell whose config runs on another number
+of chips than the cell asks for, it exits with code 2 and prints no
+result.
 """
 import time
 
@@ -22,7 +24,7 @@ from pathlib import Path  # noqa: E402
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from chipbench.harness import NoChip, run_cell  # noqa: E402
+from chipbench.harness import BadCell, NoChip, run_cell  # noqa: E402
 from chipbench.layout import Layout  # noqa: E402
 
 
@@ -36,7 +38,7 @@ def main(argv=None) -> int:
     try:
         out = run_cell(Layout(), args.workload, args.seed, args.seconds,
                        bool(args.trace), T_PROC0)
-    except NoChip as e:
+    except (NoChip, BadCell) as e:
         print(f"chipbench: {e}", file=sys.stderr)
         return 2
     for name, c in out["checks"].items():

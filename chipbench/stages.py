@@ -206,11 +206,14 @@ def tick_thread(st: Stages):
     return max(count, key=count.get) if count else None
 
 
-def reduce(raw: tracing.Raw, st: Stages, window_m: int,
-           n_metrics: int) -> dict | None:
-    """The stage figures of one traced window; None without tick spans.
-    ``window_m`` and ``n_metrics`` find the forecast kernel's calls."""
-    summary = tracing.reduce(raw)
+def reduce(raw: tracing.Raw, st: Stages, window_m: int, n_metrics: int,
+           chips: int) -> dict | None:
+    """The stage figures of one traced window of a cell on the first
+    ``chips`` devices; None without tick spans.  ``window_m`` and
+    ``n_metrics`` find the forecast kernel's calls."""
+    raw = tracing.Raw(raw.spans, tracing.on_chips(raw.ops, chips))
+    st = Stages(st.spans, tracing.on_chips(st.modules, chips))
+    summary = tracing.reduce(raw, chips)
     w = window(raw)
     if summary is None or w is None:
         return None
@@ -307,22 +310,8 @@ def stage_run(layout, workload: str, seed: int, seconds: float,
     import tempfile
 
     from chipbench import harness
-    cell = layout.cell(workload)
-    run = harness.Run(layout, cell, seed)
-    if require_tpu:
-        harness.find_chips(int(cell["chips"]))
-    import jax
-    src = layout.root / "src"
-    if str(src) not in sys.path:
-        sys.path.insert(0, str(src))
-    harness.use_compile_cache(layout.root)
-    clock = harness.CompileClock()
-    run.device = jax.devices()[0]
-    run.n_chips = len(jax.devices())
-    if require_tpu:
-        run.peaks = layout.peaks(run.device.device_kind)
-    run.say = lambda msg: print(f"[{workload} seed={seed}] {msg}",
-                                file=log, flush=True)
+    run, clock = harness.start(layout, workload, seed,
+                               require_tpu=require_tpu, log=log)
     try:
         from repro.core import obs
     except ImportError:           # a program without spans
@@ -339,7 +328,7 @@ def stage_run(layout, workload: str, seed: int, seconds: float,
         after = stats() if stats else None
         path = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")[0]
         raw = tracing.read(path)
-        run.trace = tracing.reduce(raw)
+        run.trace = tracing.reduce(raw, run.n_chips)
         st = read(path)
     finally:
         if obs is not None:
@@ -358,7 +347,7 @@ def stage_run(layout, workload: str, seed: int, seconds: float,
             per_layer[m["name"]] = value
     out["per_layer"] = per_layer
     out["stages"] = reduce(raw, st, int(cfg["window"]),
-                           int(cfg["n_metrics"]))
+                           int(cfg["n_metrics"]), run.n_chips)
     if before is not None:
         n = after["ticks"] - before["ticks"]
         out["tick_stats"] = {"before": before, "after": after}
@@ -376,11 +365,11 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--spans", type=int, choices=(0, 1), default=1)
     args = ap.parse_args(argv)
-    from chipbench.harness import NoChip
+    from chipbench.harness import BadCell, NoChip
     try:
         out = stage_run(Layout(), args.workload, args.seed, args.seconds,
                         bool(args.spans), T_PROC0)
-    except NoChip as e:
+    except (NoChip, BadCell) as e:
         print(f"chipbench: {e}", file=sys.stderr)
         return 2
     print(json.dumps(out), flush=True)

@@ -32,11 +32,20 @@ def tiny_root(tmp_path):
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     (bench / "traffic" / "tiny.json").write_text(
         json.dumps(tiny_mix("tiny", 8)))
+    # the attention deployment with its plane over four devices
+    cfg = json.loads((bench / "configs" / "ppa-attn50-w8.json").read_text())
+    cfg.update(name="ppa-attn50-w8-mesh4", device_mesh=4)
+    (bench / "configs" / "ppa-attn50-w8-mesh4.json").write_text(
+        json.dumps(cfg))
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     spec["workloads"] = [
         {"name": "lstm-tiny", "config": "ppa-lstm50", "traffic": "tiny",
          "chips": 1, "why": "tiny"},
         {"name": "attn-tiny", "config": "ppa-attn50-w8", "traffic": "tiny",
-         "chips": 1, "why": "tiny"}]
+         "chips": 1, "why": "tiny"},
+        {"name": "attn-tiny-mesh4", "config": "ppa-attn50-w8-mesh4",
+         "traffic": "tiny", "chips": 4, "why": "tiny, four devices"},
+        {"name": "attn-tiny-mesh-mismatch", "config": "ppa-attn50-w8-mesh4",
+         "traffic": "tiny", "chips": 1, "why": "config and chips differ"}]
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
     return tmp_path

@@ -72,10 +72,10 @@ def program_trace():
 def test_old_numbers_unchanged_by_the_program_spans():
     # the stage reduction reads the benchmark's trace and leaves it as is
     raw = synthetic()
-    before = tracing.reduce(raw)
+    before = tracing.reduce(raw, 1)
     st = stages.Stages([], {})
-    assert stages.reduce(raw, st, 1, 5)["metrics"] == {}
-    after = tracing.reduce(raw)
+    assert stages.reduce(raw, st, 1, 5, 1)["metrics"] == {}
+    after = tracing.reduce(raw, 1)
     assert before == after
     assert after.busy_ns == pytest.approx(9 * MS)
     assert {k: v / MS for k, v in after.idle_by_span.items()} == \
@@ -85,7 +85,7 @@ def test_old_numbers_unchanged_by_the_program_spans():
 
 def test_stage_metrics_per_tick():
     raw, st = program_trace()
-    out = stages.reduce(raw, st, 1, 5)
+    out = stages.reduce(raw, st, 1, 5, 1)
     assert out["ticks"] == 3
     assert out["metrics"] == pytest.approx({
         "upload_ms": 1.0, "dispatch_ms": 0.7, "device_wait_ms": 3.0,
@@ -137,7 +137,7 @@ def test_idle_by_stage_known_answers():
 
 def test_idle_by_stage_sums_to_the_idle_gaps():
     raw, st = program_trace()
-    out = stages.reduce(raw, st, 1, 5)
+    out = stages.reduce(raw, st, 1, 5, 1)
     assert len(out["idle_by_stage"]) == stages.TOP
     assert out["idle_by_stage"][-1][0] == "rest"
     total = sum(v for _, v in out["idle_by_stage"])

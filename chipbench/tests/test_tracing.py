@@ -33,7 +33,7 @@ def test_union_and_overlap():
 
 
 def test_window_busy_idle_and_spans():
-    s = tracing.reduce(synthetic())
+    s = tracing.reduce(synthetic(), 1)
     assert s.ticks == 3
     assert s.window_ns == 30 * MS
     # busy: the union of the ops, 3 ms per tick (the async op overlaps)
@@ -45,7 +45,7 @@ def test_window_busy_idle_and_spans():
 def test_idle_gaps_split_by_host_span():
     # the device runs 3-6 ms of every tick: idle 0-2 in collect, 2-3 and
     # 6-7 in forecast, 7-10 in decide
-    s = tracing.reduce(synthetic())
+    s = tracing.reduce(synthetic(), 1)
     idle = {k: v / MS for k, v in s.idle_by_span.items()}
     assert idle["collect"] == pytest.approx(6)
     assert idle["forecast"] == pytest.approx(6)
@@ -57,12 +57,12 @@ def test_window_clips_device_work_outside_it():
     raw = synthetic()
     raw.ops["/device:TPU:0"].append(("%late = f32[1] copy(f32[1] %x)",
                                      29 * MS, 33 * MS))
-    s = tracing.reduce(raw)
+    s = tracing.reduce(raw, 1)
     assert s.busy_ns == pytest.approx(10 * MS)
 
 
 def test_kernel_calls_by_name_and_top_ops():
-    s = tracing.reduce(synthetic())
+    s = tracing.reduce(synthetic(), 1)
     calls = tracing.kernel_calls(
         s, lambda n: 8 if "custom-call(" in n else None)
     assert [n for n, _ in calls] == [8, 8, 8]
@@ -73,4 +73,22 @@ def test_kernel_calls_by_name_and_top_ops():
 
 
 def test_no_spans_gives_nothing():
-    assert tracing.reduce(tracing.Raw({}, {})) is None
+    assert tracing.reduce(tracing.Raw({}, {}), 1) is None
+
+
+def test_planes_outside_the_cell_are_left_out():
+    # a one-chip cell on a host of four: the other chips' planes, one busy
+    # all window long and one with a plane of no ops, change nothing
+    alone = tracing.reduce(synthetic(), 1)
+    raw = synthetic()
+    raw.ops["/device:TPU:1"] = [("%x = f32[1] copy(f32[1] %y)", 0, 30 * MS)]
+    raw.ops["/device:TPU:3"] = []
+    s = tracing.reduce(raw, 1)
+    assert s.n_devices == alone.n_devices == 1
+    assert s.busy_ns == alone.busy_ns
+    assert s.idle_by_span == alone.idle_by_span
+    assert s.ops == alone.ops
+    # a two-chip cell counts its second chip
+    two = tracing.reduce(raw, 2)
+    assert two.n_devices == 2
+    assert two.busy_ns == pytest.approx((9 * MS + 30 * MS) / 2)

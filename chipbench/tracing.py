@@ -10,6 +10,9 @@ this module takes:
 * each span's durations;
 * the device ops (line ``XLA Ops`` of every ``/device:TPU:<n>`` plane),
   clipped to the window; busy time is the union of their intervals;
+  only the planes of the cell's chips count: the plane's mesh is the first
+  ``chips`` devices, ``/device:TPU:0`` to ``chips - 1``, and a one-chip
+  cell on a host of four leaves the other three out;
 * the idle gaps between them, each split among the host spans it overlaps.
 
 The profiler puts device events on the host's clock itself.  What skew
@@ -112,8 +115,20 @@ class Summary:
     idle_by_span: dict     # span name -> idle ns while the host was in it
 
 
-def reduce(raw: Raw) -> Summary | None:
-    """None when the trace holds no tick spans."""
+_DEVICE = re.compile(r"^/device:TPU:(\d+)")
+
+
+def on_chips(planes: dict, chips: int) -> dict:
+    """The entries of ``planes`` (keyed by device plane name) that belong
+    to the first ``chips`` devices."""
+    return {name: v for name, v in planes.items()
+            if (m := _DEVICE.match(name)) and int(m.group(1)) < chips}
+
+
+def reduce(raw: Raw, chips: int) -> Summary | None:
+    """The trace of a cell that runs on the first ``chips`` devices; None
+    when it holds no tick spans."""
+    raw = Raw(raw.spans, on_chips(raw.ops, chips))
     ticks = sorted(zip(sorted(raw.spans.get("collect", [])),
                        sorted(raw.spans.get("decide", []))))
     ticks = [(c[0], d[1]) for c, d in ticks]

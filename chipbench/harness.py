@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import gc
 import glob
+import os
 import shutil
 import sys
 import tempfile
@@ -31,8 +32,7 @@ from chipbench.layout import Layout
 
 WARMUP_TICKS = 4        # ticks before the window: compile, weight upload
 CHECK_TICKS = 6         # window ticks whose forecasts meet the reference
-CHECK_BLOCK = 512       # targets per block of the reference forward
-CHECK_THREADS = 8       # blocks checked at once, after the window
+CHECK_BLOCK = 64        # targets per block of the reference forward
 
 
 class NoChip(RuntimeError):
@@ -269,10 +269,20 @@ class Run:
         """The numbers that decide ``correct``, each beside its limit.  With
         ``control`` the plain reference computed in bfloat16 takes the
         program's place at the sampled ticks: its forecasts are held to the
-        same limit as the program's."""
+        same limit as the program's.
+
+        Every target is checked.  The decisions of every tick come first;
+        then the forecasts of the sampled ticks, one reference forward a
+        block of targets over all of them, the blocks on one thread per CPU
+        this process may use, with BLAS held to one thread in each.  The
+        seconds of each phase and the thread count are kept in
+        ``check_s`` and ``check_threads``."""
+        from threadpoolctl import threadpool_limits
         cfg, a = self.config, self.config["assumed"]
         mean, std = reference.scaler_stats(self.history)
+        perf = time.perf_counter
         # decisions: every tick, from the key forecast each tick decided on
+        t0 = perf()
         k = int(cfg["key_metric_idx"])
         decide = reference.Decider(a["threshold"], a["min_replicas"],
                                    cfg["tolerance"], cfg["stabilization_s"])
@@ -283,36 +293,36 @@ class Run:
                           cur, int(a["max_replicas"]))
             mismatches += int((want != dec).sum())
             cur = dec
+        t1 = perf()
         # forecasts: sampled window ticks, every target, in z units; a
-        # target with no forecast there is missing, not compared
+        # (tick, target) with no forecast there is missing, not compared
         W = int(cfg["window"])
-        wins = {j: np.stack([self.row_at(i) for i in range(j - W + 1, j + 1)],
-                            axis=1) for j in self.check_ticks}
+        ticks = self.check_ticks
 
-        def block(lo):
-            sl = slice(lo, lo + CHECK_BLOCK)
+        def forecasts(sl):
             leaves = {n: v[sl].astype(np.float64)
                       for n, v in self.leaves.items()}
-            gap = 0.0
-            missing = 0
-            for j in self.check_ticks:
-                want = reference.forecast(self.arch, leaves, mean[sl],
-                                          std[sl], wins[j][sl],
-                                          cfg["residual"])
-                got = reference.forecast(
-                    self.arch, leaves, mean[sl], std[sl], wins[j][sl],
-                    cfg["residual"], rnd=to_bf16) if control \
-                    else self.sampled[j][sl]
-                diff = np.abs(got - want) / std[sl]
-                seen = np.isfinite(diff).all(axis=1)
-                missing += int((~seen).sum())
-                if seen.any():
-                    gap = max(gap, float(np.max(diff[seen])))
-            return gap, missing
+            win = np.stack([np.stack([self.row_at(i)[sl]
+                                      for i in range(j - W + 1, j + 1)],
+                                     axis=1) for j in ticks], axis=1)
+            want = reference.forecast(self.arch, leaves, mean[sl], std[sl],
+                                      win, cfg["residual"])
+            got = reference.forecast(
+                self.arch, leaves, mean[sl], std[sl], win, cfg["residual"],
+                rnd=to_bf16) if control else np.stack(
+                    [self.sampled[j][sl] for j in ticks], axis=1)
+            diff = np.abs(got - want) / std[sl][:, None, :]
+            seen = np.isfinite(diff).all(axis=-1)
+            return (float(np.max(diff[seen])) if seen.any() else 0.0,
+                    int((~seen).sum()))
 
-        # blocks of targets on a few threads (numpy releases the GIL)
-        with ThreadPoolExecutor(CHECK_THREADS) as pool:
-            gaps = list(pool.map(block, range(0, self.Z, CHECK_BLOCK)))
+        self.check_threads = len(os.sched_getaffinity(0))
+        with threadpool_limits(1, user_api="blas"), \
+                ThreadPoolExecutor(self.check_threads) as pool:
+            gaps = list(pool.map(forecasts, [
+                slice(lo, lo + CHECK_BLOCK)
+                for lo in range(0, self.Z, CHECK_BLOCK)]))
+        self.check_s = {"decisions": t1 - t0, "forecasts": perf() - t1}
         lim = cfg["check"]
         return {"forecast_gap_z": {"value": max(g[0] for g in gaps),
                                    "limit": lim["forecast_gap_z"]},
@@ -410,9 +420,12 @@ def run_cell(layout: Layout, workload: str, seed: int, seconds: float,
                      require_tpu=require_tpu, log=log)
     t0 = time.perf_counter()
     checks = run.check()
-    run.say(f"check took {time.perf_counter() - t0:.3f} s over "
-            f"{len(run.decisions)} ticks and {len(run.check_ticks)} "
-            f"sampled; forecast_errors={run.forecast_errors}")
+    run.say(f"check took {time.perf_counter() - t0:.3f} s (decisions "
+            f"{run.check_s['decisions']:.3f} s, forecasts "
+            f"{run.check_s['forecasts']:.3f} s, {run.check_threads} "
+            f"threads) over {len(run.decisions)} ticks and "
+            f"{len(run.check_ticks)} sampled; "
+            f"forecast_errors={run.forecast_errors}")
 
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}

@@ -5,7 +5,8 @@ temporal attention whose query is the last hidden state projected by
 head.
 
 Plain reference copied from ``src/repro/kernels/ref.py``
-(``attn_lstm_seq``), in numpy over a leading target axis."""
+(``attn_lstm_seq``), in numpy over a leading target axis and a tick axis
+after it, as ``models/lstm.py``."""
 from __future__ import annotations
 
 import numpy as np
@@ -24,13 +25,13 @@ def leaf_shapes(hidden: int, n_metrics: int) -> dict:
 
 
 def forward(p: dict, z: np.ndarray, rnd) -> np.ndarray:
-    """(Z, W, M) standardised window -> (Z, M) network output."""
-    hs = lstm(z, p["Wx1"], p["Wh1"], p["b1"], rnd)      # (Z, W, H)
+    """(Z, T, W, M) standardised windows -> (Z, T, M) network output."""
+    hs = lstm(z, p["Wx1"], p["Wh1"], p["b1"], rnd)      # (Z, T, W, H)
     H = hs.shape[-1]
-    q = rnd(matvec(hs[:, -1], p["Wa"]))                 # (Z, H)
-    scores = rnd(rnd(np.einsum("zwh,zh->zw", hs, q)) * H ** -0.5)
+    q = rnd(matvec(hs[:, :, -1], p["Wa"]))              # (Z, T, H)
+    scores = rnd(rnd(np.einsum("ztwh,zth->ztw", hs, q)) * H ** -0.5)
     e = np.exp(scores - scores.max(axis=-1, keepdims=True))
-    alpha = rnd(e / e.sum(axis=-1, keepdims=True))            # (Z, W)
-    ctx = rnd(alpha[:, :, None] * hs)                         # (Z, W, H)
-    h2 = lstm(ctx, p["Wx2"], p["Wh2"], p["b2"], rnd)[:, -1]
-    return rnd(rnd(matvec(np.maximum(h2, 0.0), p["Wo"])) + p["bo"])
+    alpha = rnd(e / e.sum(axis=-1, keepdims=True))            # (Z, T, W)
+    ctx = rnd(alpha[..., None] * hs)                          # (Z, T, W, H)
+    h2 = lstm(ctx, p["Wx2"], p["Wh2"], p["b2"], rnd)[:, :, -1]
+    return rnd(rnd(matvec(np.maximum(h2, 0.0), p["Wo"])) + p["bo"][:, None])

@@ -35,13 +35,16 @@ def scaler_stats(history: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
 def forecast(arch, leaves: dict, mean, std, win, residual: bool,
              rnd=identity) -> np.ndarray:
-    """Forecast (Z, M) in metric units from windows ``win`` (Z, W, M).
-    ``rnd`` rounds every intermediate (the lower-precision control)."""
-    z = rnd(np.clip((win - mean[:, None, :]) / std[:, None, :],
+    """Forecasts (Z, T, M) in metric units from the windows ``win``
+    (Z, T, W, M) of T ticks; ``mean`` and ``std`` (Z, M) hold for every
+    tick.  ``rnd`` rounds every intermediate (the lower-precision
+    control)."""
+    mean, std = mean[:, None, :], std[:, None, :]
+    z = rnd(np.clip((win - mean[:, :, None]) / std[:, :, None],
                     -Z_CLIP, Z_CLIP))
     net = arch.forward({k: rnd(v) for k, v in leaves.items()}, z, rnd)
     if residual:
-        net = rnd(z[:, -1, :] + net)
+        net = rnd(z[:, :, -1, :] + net)
     return net * std + mean
 
 
@@ -60,22 +63,49 @@ def threshold_policy(key, cur, threshold, min_replicas, tolerance):
 class Decider:
     """Decisions of Z targets tick by tick: policy on the key metric (the
     forecast where there is one, else the current value), clamp to the
-    maximum, then the scale-down stabiliser over ``window_s``."""
+    maximum, then the scale-down stabiliser over ``window_s``.
+
+    The stabiliser's largest recommendation in the window is a running
+    maximum over two stacks of records, so that a tick takes a few
+    elementwise maxima and not one over every record in the window: the
+    older stack holds each record with the maximum of it and every later
+    record there, the newer one only the maximum of all of its records.
+    When the oldest record leaves the window and the older stack is empty,
+    the newer stack becomes the older one."""
 
     def __init__(self, threshold, min_replicas, tolerance, window_s):
         self.threshold = threshold
         self.min_replicas = min_replicas
         self.tolerance = tolerance
         self.window_s = window_s
-        self.recs: list[tuple[float, np.ndarray]] = []
+        self.older: list[tuple[float, np.ndarray]] = []
+        self.newer: list[tuple[float, np.ndarray]] = []
+        self.newer_max = None
 
     def __call__(self, t, current_key, forecast_key, cur, max_r):
         key = np.where(np.isfinite(forecast_key), forecast_key, current_key)
         n = threshold_policy(key, cur, self.threshold, self.min_replicas,
                              self.tolerance)
         n = np.minimum(n, max_r)
-        self.recs.append((t, n))
-        self.recs = [(tt, d) for tt, d in self.recs
-                     if tt >= t - self.window_s]
-        recmax = np.max([d for _, d in self.recs], axis=0)
+        self.newer.append((t, n))
+        self.newer_max = n if self.newer_max is None \
+            else np.maximum(self.newer_max, n)
+        lo = t - self.window_s
+        while True:
+            while self.older and self.older[0][0] < lo:
+                self.older.pop(0)
+            if self.older or self.newer[0][0] >= lo:
+                break
+            acc = None
+            for tt, d in reversed(self.newer):
+                acc = d if acc is None else np.maximum(acc, d)
+                self.older.append((tt, acc))
+            self.older.reverse()
+            self.newer, self.newer_max = [], None
+        if not self.older:
+            recmax = self.newer_max
+        elif self.newer_max is None:
+            recmax = self.older[0][1]
+        else:
+            recmax = np.maximum(self.older[0][1], self.newer_max)
         return np.where(n < cur, np.minimum(recmax, max_r), n)
